@@ -835,15 +835,23 @@ def obs_overhead(sink: C.CsvSink, small: bool) -> None:
               on_vs_off=round(best[True] / max(best[False], 1e-9), 3),
               identical=True)
 
-    # ---- sharded leg: P=8 forced host devices in a fresh process (the
-    # XLA device-count flag must precede jax init); the worker runs the
-    # same interleaved on/off protocol + in-run §10 asserts and emits
-    # OBSROW json lines this section re-emits for the regression gate
+    # ---- sharded leg.  On the CPU: P=8 forced host devices in a fresh
+    # process (the XLA device-count flag must precede jax init); the
+    # worker runs the same interleaved on/off protocol + in-run §10
+    # asserts and emits OBSROW json lines this section re-emits for the
+    # regression gate.  On a TPU: the same leg in this process over the
+    # real devices — a child cannot reach the chip this process holds.
     import json
     import os
     import subprocess
     import sys
 
+    from repro.kernels.relax import config as kernel_config
+    if kernel_config.on_tpu():
+        from benchmarks import obs_worker
+        for rec in obs_worker.run(small):
+            sink.emit(rec.pop("bench"), **rec)
+        return
     cmd = [sys.executable, "-m", "benchmarks.obs_worker"]
     if small:
         cmd.append("--small")
@@ -870,25 +878,35 @@ def scale(sink: C.CsvSink, small: bool) -> None:
     acceptance point N=1M / E=10M.  The smallest size cross-checks the
     final tree against the Dijkstra oracle; the regression gate
     (check_regression.gate_scale) holds the events/s floor and the RSS
-    ceiling from this PR onward."""
+    ceiling from this PR onward.  On a TPU every size runs in this
+    process (a child cannot reach the chip this process holds), so peak
+    RSS is the bench process's high-water mark there."""
     import json
     import os
     import subprocess
     import sys
 
+    from benchmarks import scale_worker
+    from repro.kernels.relax import config as kernel_config
+
     sizes = [1 << 16, 1 << 18] + ([] if small else [1 << 20])
     for n in sizes:
-        cmd = [sys.executable, "-m", "benchmarks.scale_worker",
-               "--n", str(n), "--e", str(10 * n)]
-        if n == sizes[0]:
-            cmd.append("--check-oracle")
-        env = dict(os.environ)
-        env["PYTHONPATH"] = os.pathsep.join(
-            p for p in ("src", env.get("PYTHONPATH", "")) if p)
-        out = subprocess.run(cmd, capture_output=True, text=True, env=env)
-        assert out.returncode == 0, (
-            f"scale worker n={n} failed:\n{out.stderr[-2000:]}")
-        rec = json.loads(out.stdout.strip().splitlines()[-1])
+        if kernel_config.on_tpu():
+            rec = scale_worker.measure(n, 10 * n,
+                                       check_oracle=n == sizes[0])
+        else:
+            cmd = [sys.executable, "-m", "benchmarks.scale_worker",
+                   "--n", str(n), "--e", str(10 * n)]
+            if n == sizes[0]:
+                cmd.append("--check-oracle")
+            env = dict(os.environ)
+            env["PYTHONPATH"] = os.pathsep.join(
+                p for p in ("src", env.get("PYTHONPATH", "")) if p)
+            out = subprocess.run(cmd, capture_output=True, text=True,
+                                 env=env)
+            assert out.returncode == 0, (
+                f"scale worker n={n} failed:\n{out.stderr[-2000:]}")
+            rec = json.loads(out.stdout.strip().splitlines()[-1])
         assert rec["rss_ok"], (
             f"scale n={n}: peak RSS {rec['peak_rss_mb']}MB over budget "
             f"{rec['rss_budget_mb']}MB")

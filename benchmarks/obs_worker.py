@@ -1,10 +1,13 @@
 """Sharded obs-overhead worker: the P=8 leg of the ``obs_overhead``
 section, measured in a FRESH process.
 
-Run by benchmarks/bench_sssp.py via ``python -m benchmarks.obs_worker``;
-a subprocess because ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-must be set BEFORE jax initializes, and the parent bench process has long
-since imported jax.
+On the CPU, benchmarks/bench_sssp.py runs it via ``python -m
+benchmarks.obs_worker``: a subprocess because
+``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (which only the CPU
+platform reads) must be set BEFORE jax initializes, and the parent bench
+process has long since imported jax.  On a TPU the bench calls ``run`` in
+its own process over the real devices: a child cannot reach the chip its
+parent holds.
 
 Same contract as the single-device leg (DESIGN.md §10.4), on the sharded
 engine over an 8-device mesh: the identical power-law stream ingested
@@ -25,7 +28,6 @@ from __future__ import annotations
 import os
 import sys
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import argparse
@@ -35,16 +37,9 @@ import time
 import numpy as np
 
 
-def emit(bench: str, **kv) -> None:
-    print("OBSROW " + json.dumps({"bench": bench, **kv}), flush=True)
-
-
-def main() -> int:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--small", action="store_true")
-    ap.add_argument("--trace-out", default=None)
-    args = ap.parse_args()
-
+def run(small: bool, trace_out: str | None = None) -> list[dict]:
+    """The sharded leg over every visible device; returns the bench
+    records (``{"bench": name, **fields}``)."""
     import jax
 
     from repro.core.dist_engine import ShardedEngineConfig, \
@@ -55,7 +50,7 @@ def main() -> int:
     from repro.obs import WatchdogConfig
 
     P = len(jax.devices())
-    n = (1 << 9) if args.small else (1 << 10)
+    n = (1 << 9) if small else (1 << 10)
     m = 4 * n
     nv, src, dst, w = gen.power_law_hubs(n, m, n_hubs=4, seed=31,
                                          orientation="in")
@@ -111,26 +106,42 @@ def main() -> int:
     assert "watchdog_warnings" not in ct, ct.get("watchdog_warnings")
 
     from benchmarks import common as C
+    records = []
     for obs_on in (False, True):
         eng = final[obs_on]
         s = eng.metrics_snapshot()
-        emit("obs_overhead", dataset="plaw", n=nv, edges=m,
-             backend="sliced", engine="sharded", parts=P,
-             observability=obs_on, events=len(log),
-             events_per_s=round(best[obs_on], 1), epochs=eng.n_epochs,
-             rounds=int(s["rounds"]), messages=int(s["messages"]),
-             spans=sum(s["spans"].values()),
-             **(C.hist_fields(s) if obs_on else {}))
-    emit("obs_overhead_summary", backend="sliced", engine="sharded",
-         parts=P,
-         on_vs_off=round(best[True] / max(best[False], 1e-9), 3),
-         identical=True)
+        records.append(dict(
+            bench="obs_overhead", dataset="plaw", n=nv, edges=m,
+            backend="sliced", engine="sharded", parts=P,
+            observability=obs_on, events=len(log),
+            events_per_s=round(best[obs_on], 1), epochs=eng.n_epochs,
+            rounds=int(s["rounds"]), messages=int(s["messages"]),
+            spans=sum(s["spans"].values()),
+            **(C.hist_fields(s) if obs_on else {})))
+    records.append(dict(
+        bench="obs_overhead_summary", backend="sliced", engine="sharded",
+        parts=P, on_vs_off=round(best[True] / max(best[False], 1e-9), 3),
+        identical=True))
 
-    if args.trace_out:
-        on.obs.tracer.save_chrome(args.trace_out)
-        print(f"chrome trace -> {args.trace_out}", file=sys.stderr)
+    if trace_out:
+        on.obs.tracer.save_chrome(trace_out)
+        print(f"chrome trace -> {trace_out}", file=sys.stderr)
+    return records
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--small", action="store_true")
+    ap.add_argument("--trace-out", default=None)
+    args = ap.parse_args()
+    for rec in run(args.small, args.trace_out):
+        print("OBSROW " + json.dumps(rec), flush=True)
     return 0
 
 
 if __name__ == "__main__":
+    # eight host devices for the P=8 leg; the flag is read by the CPU
+    # platform only and must precede jax's initialization
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
     raise SystemExit(main())

@@ -174,6 +174,8 @@ def main() -> int:
         print(f"error: unknown --only section(s): {','.join(unknown)}; "
               f"--list prints the available names", file=sys.stderr)
         return 2
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     sink = C.CsvSink()
     t0 = time.perf_counter()
     run_sssp(sink, args.small, args.only)

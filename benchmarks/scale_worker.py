@@ -35,6 +35,10 @@ The point of the bound: it scales with POOL CAPACITY and CHUNK size
 only — a control plane or replay path that held O(stream) Python
 objects (the pre-§11 dict planner at E ≥ 10M) blows straight past it.
 
+On a TPU the bench calls ``measure`` in its own process instead (a child
+cannot reach the chip its parent holds), so peak RSS there is the whole
+bench process's high-water mark.
+
 Emits one JSON line on stdout; benchmarks/bench_sssp.py turns it into a
 ``scale`` record gated by check_regression (events/s floor, RSS
 ceiling, oracle parity at the smallest size).
@@ -64,31 +68,24 @@ def peak_rss_mb() -> float:
     return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024.0
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--n", type=int, required=True)
-    ap.add_argument("--e", type=int, required=True)
-    ap.add_argument("--chunk", type=int, default=1 << 16)
-    ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--alloc-impl", default="columnar")
-    ap.add_argument("--check-oracle", action="store_true")
-    args = ap.parse_args()
-
+def measure(n: int, e: int, *, chunk: int = 1 << 16, seed: int = 0,
+            alloc_impl: str = "columnar", check_oracle: bool = False
+            ) -> dict:
+    """Ingest one synthetic (n, e) stream; returns the ``scale`` record."""
     import repro
     from repro.core import events as ev
 
-    n, e, chunk = args.n, args.e, args.chunk
     cap = e + 64
     eng = repro.make_engine(
         num_vertices=n, edge_capacity=cap, source=0,
         wave_schedule="buckets", bucket_width=float("inf"),
-        alloc_impl=args.alloc_impl)
+        alloc_impl=alloc_impl)
 
     def synth_chunks():
         done, i = 0, 0
         while done < e:
             m = min(chunk, e - done)
-            rng = np.random.default_rng((args.seed << 20) + i)
+            rng = np.random.default_rng((seed << 20) + i)
             src = rng.integers(0, n, m, dtype=np.int64)
             dst = rng.integers(0, n, m, dtype=np.int64)
             w = rng.uniform(0.1, 1.0, m).astype(np.float32)
@@ -106,7 +103,7 @@ def main() -> int:
     budget_mb = rss_budget_mb(n, cap)
 
     oracle_match = None
-    if args.check_oracle:
+    if check_oracle:
         from repro.core import oracle
         lsrc, ldst, lw = eng.alloc.active_coo()
         dist_ref, _ = oracle.dijkstra(n, lsrc, ldst, lw, 0)
@@ -117,7 +114,7 @@ def main() -> int:
             rtol=1e-5, atol=1e-5))
 
     rec = {
-        "n": n, "e": e, "chunk": chunk, "alloc_impl": args.alloc_impl,
+        "n": n, "e": e, "chunk": chunk, "alloc_impl": alloc_impl,
         "live_edges": int(eng.alloc.mactive.sum()),
         "events_per_s": round(e / max(ingest_s, 1e-9), 1),
         "ingest_s": round(ingest_s, 3),
@@ -130,7 +127,21 @@ def main() -> int:
     }
     if oracle_match is not None:
         rec["oracle_match"] = oracle_match
-    print(json.dumps(rec))
+    return rec
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--n", type=int, required=True)
+    ap.add_argument("--e", type=int, required=True)
+    ap.add_argument("--chunk", type=int, default=1 << 16)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--alloc-impl", default="columnar")
+    ap.add_argument("--check-oracle", action="store_true")
+    args = ap.parse_args()
+    print(json.dumps(measure(args.n, args.e, chunk=args.chunk,
+                             seed=args.seed, alloc_impl=args.alloc_impl,
+                             check_oracle=args.check_oracle)))
     return 0
 
 

@@ -49,6 +49,7 @@ import numpy as np
 import jax
 
 import repro
+from repro.compile_cache import enable_compile_cache
 from repro.core import events as ev
 from repro.core.engine import RELAX_BACKENDS
 from repro.graphs import generators as gen
@@ -91,6 +92,7 @@ def main():
                         "(core/buckets.py, DESIGN.md §9) on both engines")
     add_obs_flags(p)
     args = p.parse_args()
+    enable_compile_cache()
     # fail fast on unwritable observability destinations (exit 2)
     for path in obs_paths(args):
         if path:

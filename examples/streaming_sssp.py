@@ -41,6 +41,7 @@ import time
 import numpy as np
 
 import repro
+from repro.compile_cache import enable_compile_cache
 from repro.core import events as ev
 from repro.core.baseline import ReMoBaseline
 from repro.graphs import generators as gen
@@ -120,6 +121,7 @@ def main():
                         "report the serving metrics (unknown paths exit 2)")
     add_obs_flags(p)
     args = p.parse_args()
+    enable_compile_cache()
     # fail fast on unwritable observability destinations (exit 2)
     for path in obs_paths(args):
         if path:

@@ -32,6 +32,8 @@ from typing import TYPE_CHECKING, Any, Callable, ClassVar
 import jax
 import numpy as np
 
+from repro.kernels.relax import config as kernel_config
+
 if TYPE_CHECKING:  # only for annotations; no runtime import cycles
     from repro.core.ingest import PlannedAdds, SlotAllocator
     from repro.core.relax import RelaxStats
@@ -82,6 +84,7 @@ def validate_backend_config(cfg: Any) -> None:
     backend — instead of failing deep inside layout init (or, worse,
     silently ignoring a knob the user believes they tuned).  Shared by
     ``EngineConfig`` and ``ShardedEngineConfig`` (__post_init__)."""
+    kernel_config.check_kernel_request(cfg)
     name = getattr(cfg, "relax_backend", "segment")
     if name not in BACKENDS and name != AUTO_BACKEND:
         raise ValueError(

@@ -54,6 +54,7 @@ from repro.core.backends.base import (ELL_BLOWUP_RATIO, RelaxBackend,
 from repro.core.relax import RelaxStats
 from repro.core.state import INF, NO_PARENT, SSSPState
 from repro.graphs import csr as csr_mod
+from repro.kernels.relax import config as kernel_config
 from repro.kernels.relax.ops import relax_wave
 
 _NEG_INF = jnp.float32(-jnp.inf)
@@ -532,10 +533,8 @@ class ShardedEllpack(ShardedBackend):
     def __init__(self, cfg, ds, allocs):
         super().__init__(cfg, ds, allocs)
         self.P, self.npp = ds.P, ds.npp
-        on_tpu = jax.default_backend() == "tpu"
-        self.use_kernel = (on_tpu if cfg.ell_use_kernel is None
-                           else cfg.ell_use_kernel)
-        self.interpret = not on_tpu
+        self.use_kernel = bool(cfg.ell_use_kernel)
+        self.interpret = kernel_config.default_interpret()
         self.planners = [
             EllPlanner(self.npp, block_rows=cfg.ell_block_rows,
                        init_k=cfg.ell_init_k, row0=p * self.npp)

@@ -44,6 +44,7 @@ from repro.core.backends.base import (RelaxBackend, ShardedBackend, register,
 from repro.core.relax import RelaxStats
 from repro.core.state import INF, NO_PARENT, SSSPState
 from repro.graphs import csr as csr_mod
+from repro.kernels.relax import config as kernel_config
 
 _NEG_INF = jnp.float32(-jnp.inf)
 _INT_MAX = jnp.int32(2**31 - 1)
@@ -188,33 +189,54 @@ def sliced_gather_min(offers: jax.Array, flat_idx: jax.Array,
     len(widths) * slice_rows rows; arg is the smallest minimizing neighbor
     id (the shared tie rule).
 
-    Runs of equal-width slices are contiguous row-major (R_g, k) blocks in
-    the flat buffer — merge them so the common all-settled-on-one-width
-    case is a single dense wave, not one dispatch per slice.  The Pallas
-    kernel tiles rows in 256-row blocks and requires R_g % min(256, R_g)
-    == 0, so a merged run is split into a multiple-of-256-rows main block
-    plus a sub-256-row remainder block.
+    Slices are processed by width class, not by contiguous run: a
+    power-law layout alternates widths from slice to slice (R-MAT scale
+    20: 845 runs over 5 widths), and a block per run costs compile time
+    and scratch memory in proportion to the run count (16 GB on a v5e at
+    scale 20, against 1 GB by class).  A slice of width k is k consecutive
+    ``slice_rows``-cell lines of the flat buffer, so one line gather
+    (static line ids) pulls every slice of width k into one
+    ``(rows_k, k)`` block, and a slice-row scatter puts the results back
+    in row order.  (A gather of whole slices as windows takes a minute to
+    compile for a TPU; the line gather takes a second.)  The Pallas
+    kernel requires its rows to divide into ``min(256, rows)`` blocks, so
+    its class block is split as ``slice_run_groups`` splits a run.
     """
     from repro.kernels.relax.fused import slice_run_groups
     from repro.kernels.relax.ref import ellpack_relax_ref
     from repro.kernels.relax.relax import ellpack_relax
 
-    groups = slice_run_groups(widths, slice_rows)
-    bests, args_ = [], []
-    off = 0
-    for k, cnt in groups:                  # static unroll: one block per run
-        rows_g = slice_rows * cnt
-        blk = slice(off, off + rows_g * k)
-        blk_idx = flat_idx[blk].reshape(rows_g, k)
-        blk_w = flat_w[blk].reshape(rows_g, k)
+    sr = slice_rows
+    wid = np.asarray(widths, np.int64)
+    line0 = csr_mod.sliced_geometry(widths, sr)[0][:-1] // sr
+    best = jnp.full((len(widths), sr), INF)
+    arg = jnp.full((len(widths), sr), -1, jnp.int32)
+    for k in sorted(set(widths)):
+        sl = np.flatnonzero(wid == k)
+        lines = jnp.asarray((line0[sl, None] + np.arange(k)).reshape(-1),
+                            jnp.int32)
+
+        def take(buf, k=k, lines=lines):
+            return buf.reshape(-1, sr)[lines].reshape(len(sl) * sr, k)
+
+        idx, w = take(flat_idx), take(flat_w)
         if use_kernel:
-            b, a = ellpack_relax(offers, blk_idx, blk_w, interpret=interpret)
+            parts, off = [], 0
+            for _, cnt in slice_run_groups((k,) * len(sl), sr):
+                blk = slice(off, off + cnt * sr)
+                parts.append(ellpack_relax(offers, idx[blk], w[blk],
+                                           interpret=interpret))
+                off += cnt * sr
+            b = jnp.concatenate([p[0] for p in parts])
+            a = jnp.concatenate([p[1] for p in parts])
         else:
-            b, a = ellpack_relax_ref(offers, blk_idx, blk_w)
-        bests.append(b)
-        args_.append(a)
-        off += rows_g * k
-    return jnp.concatenate(bests), jnp.concatenate(args_)
+            b, a = ellpack_relax_ref(offers, idx, w)
+        rows = jnp.asarray(sl, jnp.int32)
+        best = best.at[rows].set(b.reshape(len(sl), sr),
+                                 indices_are_sorted=True, unique_indices=True)
+        arg = arg.at[rows].set(a.reshape(len(sl), sr),
+                               indices_are_sorted=True, unique_indices=True)
+    return best.reshape(-1), arg.reshape(-1)
 
 
 def overflow_min(offers: jax.Array, osrc: jax.Array, odst: jax.Array,
@@ -761,10 +783,8 @@ class ShardedSliced(ShardedBackend):
     def __init__(self, cfg, ds, allocs):
         super().__init__(cfg, ds, allocs)
         self.P, self.npp = ds.P, ds.npp
-        on_tpu = jax.default_backend() == "tpu"
-        self.use_kernel = (on_tpu if cfg.ell_use_kernel is None
-                           else cfg.ell_use_kernel)
-        self.interpret = not on_tpu
+        self.use_kernel = bool(cfg.ell_use_kernel)
+        self.interpret = kernel_config.default_interpret()
         self.planners = [
             SlicedEllPlanner(self.npp, slice_rows=cfg.sliced_slice_rows,
                              hub_k=cfg.sliced_hub_k,

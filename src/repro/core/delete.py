@@ -104,12 +104,17 @@ def pull_once(dist: jax.Array, parent: jax.Array, edges: EdgePool,
     """One bulk DistanceQuery wave (Listing 9): affected vertices pull their
     best offer from valid (finite-dist) in-neighbours.  Returns
     (dist', parent', improved) — the improved mask is the push frontier the
-    recomputation (or the bucketed drain) continues from."""
-    live = edges.active & aff[edges.dst] & jnp.isfinite(dist[edges.src])
-    cand = jnp.where(live, dist[edges.src] + edges.w, INF)
+    recomputation (or the bucketed drain) continues from.
+
+    Like ``relax.relax_round`` it makes two [E] gathers and two scatters:
+    the affected mask applies to the [N] result, and invalid (+inf)
+    sources offer +inf, so the candidates of affected rows are exactly the
+    live edges from finite-dist in-neighbours."""
+    cand = jnp.where(edges.active, dist[edges.src] + edges.w, INF)
     best = jax.ops.segment_min(cand, edges.dst, num_segments=num_vertices)
-    improved = best < dist
-    hit = live & (cand == best[edges.dst]) & improved[edges.dst]
+    improved = aff & (best < dist)
+    target = jnp.where(improved, best, -INF)
+    hit = cand == target[edges.dst]
     cand_src = jnp.where(hit, edges.src, jnp.int32(2**31 - 1))
     new_parent = jax.ops.segment_min(cand_src, edges.dst,
                                      num_segments=num_vertices)

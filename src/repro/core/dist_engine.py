@@ -80,7 +80,6 @@ from repro.core import frontier as frontier_mod
 from repro.core import ingest
 from repro.core.backends.base import SHARDED_BACKENDS
 from repro.core.distributed import (DistConfig, DistributedSSSP,
-                                    _SHARD_MAP_KW, _shard_map,
                                     inactive_dst_layout,
                                     per_partition_occupancy)
 from repro.core.state import INF, NO_PARENT
@@ -116,7 +115,7 @@ class ShardedEngineConfig:
     relax_backend: str = "segment"
     ell_block_rows: int = 256
     ell_init_k: int = 8
-    ell_use_kernel: bool | None = None  # None = Pallas kernel iff on TPU
+    ell_use_kernel: bool = False  # Pallas ELL row-min kernel (DESIGN.md §2.7)
     sliced_slice_rows: int = 256
     sliced_hub_k: int = 32
     sliced_init_k: int = 2
@@ -634,10 +633,10 @@ def _build_epochs(ds: DistributedSSSP, epp: int, use_doubling: bool,
         return jnp.where(mine, gslot - my_p * epp, epp)
 
     @jax.jit
-    @partial(_shard_map, mesh=ds.mesh,
+    @partial(jax.shard_map, mesh=ds.mesh,
              in_specs=(v, v, e, e, e, e) + extra_specs + (r, r, r, r, r, r),
              out_specs=(v, v, e, e, e, e, r, r),
-             **_SHARD_MAP_KW)
+             check_vma=False)
     def add_epoch(dist, parent, esrc, edst, ew, eact, *rest):
         """patch pools + relax from the inserted tails, one fused epoch.
         Layout extras arrive already patched (staged before the epoch)."""
@@ -661,10 +660,10 @@ def _build_epochs(ds: DistributedSSSP, epp: int, use_doubling: bool,
                 racc + rounds, macc + msgs)
 
     @jax.jit
-    @partial(_shard_map, mesh=ds.mesh,
+    @partial(jax.shard_map, mesh=ds.mesh,
              in_specs=(v, v, e, e, e, e) + extra_specs + (r, r, r, r, r),
              out_specs=(v, v, e) + (v,) * len(del_mutated) + (r, r),
-             **_SHARD_MAP_KW)
+             check_vma=False)
     def del_epoch(dist, parent, esrc, edst, ew, eact, *rest):
         """seed from pre-deletion tree + deactivate + tombstone layout +
         invalidate + recompute, one fused epoch.  Stats mirror
@@ -725,10 +724,10 @@ def _build_epochs(ds: DistributedSSSP, epp: int, use_doubling: bool,
 
     # ---------------------------------------- bucketed (lazy) epoch variants
     @jax.jit
-    @partial(_shard_map, mesh=ds.mesh,
+    @partial(jax.shard_map, mesh=ds.mesh,
              in_specs=(v, e, e, e, e, v, r, r, r, r),
              out_specs=(e, e, e, e, v),
-             **_SHARD_MAP_KW)
+             check_vma=False)
     def add_epoch_lazy(dist, esrc, edst, ew, eact, push,
                        gslot, bsrc, bdst, bw):
         """Bucketed ADD: patch the pools + enqueue the inserted tails as
@@ -748,10 +747,10 @@ def _build_epochs(ds: DistributedSSSP, epp: int, use_doubling: bool,
         return esrc, edst, ew, eact, push
 
     @jax.jit
-    @partial(_shard_map, mesh=ds.mesh,
+    @partial(jax.shard_map, mesh=ds.mesh,
              in_specs=(v, v, e) + extra_specs + (v, v, r, r, r, r, r),
              out_specs=(v, v, e) + (v,) * len(del_mutated) + (v, v, r, r),
-             **_SHARD_MAP_KW)
+             check_vma=False)
     def del_epoch_lazy(dist, parent, eact, *rest):
         """Bucketed DEL: seed + deactivate + tombstone + invalidate — the
         immediate work the witness-invariant argument requires — with the
@@ -794,10 +793,10 @@ def _build_epochs(ds: DistributedSSSP, epp: int, use_doubling: bool,
                 push, pull, racc + d_rounds, macc + affected)
 
     @jax.jit
-    @partial(_shard_map, mesh=ds.mesh,
+    @partial(jax.shard_map, mesh=ds.mesh,
              in_specs=(v, v, e, e, e, e) + extra_specs + (v, v, r, r),
              out_specs=(v, v, r, r),
-             **_SHARD_MAP_KW)
+             check_vma=False)
     def drain_epoch(dist, parent, esrc, edst, ew, eact, *rest):
         """Settle the pending masks bucket-by-bucket with the backend's
         wave; the caller resets (push, pull) to zeros afterwards."""
@@ -857,10 +856,10 @@ def _build_epochs_ms(ds: DistributedSSSP, epp: int, use_doubling: bool,
         return jnp.where(mine, gslot - my_p * epp, epp)
 
     @jax.jit
-    @partial(_shard_map, mesh=ds.mesh,
+    @partial(jax.shard_map, mesh=ds.mesh,
              in_specs=(vb, vb, e, e, e, e) + extra_specs + (r, r, r, r, r, r),
              out_specs=(vb, vb, e, e, e, e, r, r),
-             **_SHARD_MAP_KW)
+             check_vma=False)
     def add_epoch(dist, parent, esrc, edst, ew, eact, *rest):
         """One shared pool patch + the SAME insertion frontier broadcast to
         every lane (ADD tails are source-independent), then the batched
@@ -885,10 +884,10 @@ def _build_epochs_ms(ds: DistributedSSSP, epp: int, use_doubling: bool,
                 racc + rounds, macc + msgs)
 
     @jax.jit
-    @partial(_shard_map, mesh=ds.mesh,
+    @partial(jax.shard_map, mesh=ds.mesh,
              in_specs=(vb, vb, e, e, e, e) + extra_specs + (r, r, r, r, r),
              out_specs=(vb, vb, e) + (v,) * len(del_mutated) + (r, r),
-             **_SHARD_MAP_KW)
+             check_vma=False)
     def del_epoch(dist, parent, esrc, edst, ew, eact, *rest):
         """Per-lane seeds (a deletion is a tree edge per lane or not) +
         ONE shared deactivate/tombstone + per-lane invalidate/recompute.
@@ -943,10 +942,10 @@ def _build_epochs_ms(ds: DistributedSSSP, epp: int, use_doubling: bool,
 
     # ---------------------------------------- bucketed (lazy) epoch variants
     @jax.jit
-    @partial(_shard_map, mesh=ds.mesh,
+    @partial(jax.shard_map, mesh=ds.mesh,
              in_specs=(vb, e, e, e, e, vb, r, r, r, r),
              out_specs=(e, e, e, e, vb),
-             **_SHARD_MAP_KW)
+             check_vma=False)
     def add_epoch_lazy(dist, esrc, edst, ew, eact, push,
                        gslot, bsrc, bdst, bw):
         """Bucketed ADD: one shared pool patch + the shared tail frontier
@@ -965,10 +964,10 @@ def _build_epochs_ms(ds: DistributedSSSP, epp: int, use_doubling: bool,
         return esrc, edst, ew, eact, push
 
     @jax.jit
-    @partial(_shard_map, mesh=ds.mesh,
+    @partial(jax.shard_map, mesh=ds.mesh,
              in_specs=(vb, vb, e) + extra_specs + (vb, vb, r, r, r, r, r),
              out_specs=(vb, vb, e) + (v,) * len(del_mutated) + (vb, vb, r, r),
-             **_SHARD_MAP_KW)
+             check_vma=False)
     def del_epoch_lazy(dist, parent, eact, *rest):
         """Bucketed DEL: per-lane seeds + ONE shared deactivate/tombstone +
         per-lane gated invalidation; recompute deferred into (push, pull)."""
@@ -1012,10 +1011,10 @@ def _build_epochs_ms(ds: DistributedSSSP, epp: int, use_doubling: bool,
                 push, pull, racc + d_rounds, macc + affected)
 
     @jax.jit
-    @partial(_shard_map, mesh=ds.mesh,
+    @partial(jax.shard_map, mesh=ds.mesh,
              in_specs=(vb, vb, e, e, e, e) + extra_specs + (vb, vb, r, r),
              out_specs=(vb, vb, r, r),
-             **_SHARD_MAP_KW)
+             check_vma=False)
     def drain_epoch(dist, parent, esrc, edst, ew, eact, *rest):
         """Batched drain: per-lane bucket pacing with the vmapped wave."""
         extras = rest[:n_extra]

@@ -39,13 +39,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 exposes shard_map at top level (kwarg: check_vma)
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-except AttributeError:  # older jax: experimental module (kwarg: check_rep)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = {"check_rep": False}
-
 from repro.core.backends.segment import shard_segment_wave
 from repro.core.state import INF, NO_PARENT
 from repro.graphs import csr as csr_mod
@@ -260,11 +253,11 @@ class DistributedSSSP:
         cfg = self.cfg
 
         @jax.jit
-        @partial(_shard_map, mesh=self.mesh,
+        @partial(jax.shard_map, mesh=self.mesh,
                  in_specs=(self.vspec, self.vspec, self.vspec,
                            self.espec, self.espec, self.espec, self.espec),
                  out_specs=(self.vspec, self.vspec, self.rspec),
-                 **_SHARD_MAP_KW)
+                 check_vma=False)
         def epoch(dist, parent, frontier, esrc, edst, ew, eact):
             row0 = jnp.int32(self._flat_index()) * self.npp
             wave = shard_segment_wave(esrc, edst, ew, eact, row0, self.npp)
@@ -284,11 +277,11 @@ class DistributedSSSP:
         ax = self.cfg.mesh_axes
 
         @jax.jit
-        @partial(_shard_map, mesh=self.mesh,
+        @partial(jax.shard_map, mesh=self.mesh,
                  in_specs=(self.vspec, self.vspec, self.vspec,
                            self.espec, self.espec, self.espec, self.espec),
                  out_specs=(self.vspec, self.vspec, self.rspec),
-                 **_SHARD_MAP_KW)
+                 check_vma=False)
         def delete_epoch(dist, parent, seed, esrc, edst, ew, eact):
             row0 = jnp.int32(self._flat_index()) * self.npp
             wave = shard_segment_wave(esrc, edst, ew, eact, row0, self.npp)
@@ -921,10 +914,10 @@ class DistributedSSSP:
         iff it was a tree edge (Listing 4)."""
 
         @jax.jit
-        @partial(_shard_map, mesh=self.mesh,
+        @partial(jax.shard_map, mesh=self.mesh,
                  in_specs=(self.vspec, self.rspec, self.rspec),
                  out_specs=self.vspec,
-                 **_SHARD_MAP_KW)
+                 check_vma=False)
         def seed_fn(parent, del_src, del_dst):
             row0 = jnp.int32(self._flat_index()) * self.npp
             local = (del_dst >= row0) & (del_dst < row0 + self.npp) & (del_dst >= 0)

@@ -22,7 +22,7 @@ Beyond-paper switches:
   * ``relax_backend`` — any registered ``RelaxBackend`` (core/backends/,
     DESIGN.md §7): "segment" (scatter-min over the COO pool), "ellpack"
     (dense gather + row-min over an incrementally maintained ELLPACK block;
-    the Pallas kernel's layout — DESIGN.md §2), or "sliced" (hub-aware
+    the Pallas kernel's layout — DESIGN.md §2.7), or "sliced" (hub-aware
     hybrid: per-slice-width ELL + overflow COO lane for power-law hubs —
     DESIGN.md §6).  The engine itself is backend-agnostic: the ingest path
     calls the protocol's ``apply_adds`` / ``apply_dels`` / ``relax`` /
@@ -51,6 +51,7 @@ from repro.core import ingest, relax
 from repro.core.backends import RELAX_BACKENDS
 from repro.core.state import EdgePool, GraphState, SSSPState
 from repro.core.stream import QueryResult, StreamEngineBase
+from repro.kernels.relax import config as kernel_config
 from repro.obs import WatchdogConfig
 
 __all__ = ["EngineConfig", "QueryResult", "SSSPDelEngine", "RELAX_BACKENDS"]
@@ -68,7 +69,9 @@ class EngineConfig:
     relax_backend: str = "segment"
     ell_block_rows: int = 256   # relax-kernel row tile (rebuilds pad to this)
     ell_init_k: int = 8         # initial ELL width; doubles on overflow
-    ell_use_kernel: bool | None = None  # None = Pallas kernel iff on TPU
+    # Pallas ELL row-min kernel (kernels/relax/relax.py) instead of the XLA
+    # wave; off by default on every platform (DESIGN.md §2.7)
+    ell_use_kernel: bool = False
     # "sliced" backend knobs (DESIGN.md §6)
     sliced_slice_rows: int = 256  # rows per degree slice (per-slice K)
     sliced_hub_k: int = 32        # hub threshold: rows past it spill to COO
@@ -143,16 +146,16 @@ class SSSPDelEngine(StreamEngineBase):
             self.state = dataclasses.replace(
                 self.state, sssp=SSSPState.init_batched(
                     cfg.num_vertices, self.sources))
-        on_tpu = jax.default_backend() == "tpu"
-        use_kernel = on_tpu if cfg.ell_use_kernel is None else cfg.ell_use_kernel
-        self._use_kernel, self._interpret = use_kernel, not on_tpu
+        use_kernel = bool(cfg.ell_use_kernel)
+        self._use_kernel = use_kernel
+        self._interpret = kernel_config.default_interpret()
         # "auto" starts on the dense ELL layout and falls back to sliced when
         # a rebuild reports hub blowup (backends/base.py ELL_BLOWUP_RATIO)
         self._auto = cfg.relax_backend == bk_mod.AUTO_BACKEND
         self.backend_name = "ellpack" if self._auto else cfg.relax_backend
         self.backend = bk_mod.make_backend(
             self.backend_name, cfg, use_kernel=use_kernel,
-            interpret=not on_tpu)
+            interpret=self._interpret)
         self.bucketed = cfg.wave_schedule == "buckets"
         self._pend = buckets.empty_pending(
             cfg.num_vertices,

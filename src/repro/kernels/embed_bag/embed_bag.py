@@ -29,7 +29,7 @@ def _make_kernel(bb: int, L: int, agg: str, out_dtype):
                 i = idx_ref[b, l]
                 valid = i >= 0
                 safe = jnp.maximum(i, 0)
-                row = pl.load(table_ref, (pl.dslice(safe, 1), slice(None)))
+                row = table_ref[pl.ds(safe, 1), :]
                 row = row.astype(jnp.float32)
                 return ac.at[b].add(jnp.where(valid, row[0], 0.0))
             return jax.lax.fori_loop(0, L, slot_body, acc)

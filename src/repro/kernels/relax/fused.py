@@ -160,9 +160,8 @@ def fused_sliced_relax(dist: jax.Array, active: jax.Array,
         blk_idx = flat_idx[blk].reshape(rows_g, k)
         blk_w = flat_w[blk].reshape(rows_g, k)
         cost = pl.CostEstimate(
-            flops=3.0 * rows_g * k + 4.0 * C,
-            bytes_accessed=float(5 * n + 8 * rows_g * k + 12 * C
-                                 + 8 * rows_g),
+            flops=3 * rows_g * k + 4 * C,
+            bytes_accessed=5 * n + 8 * rows_g * k + 12 * C + 8 * rows_g,
             transcendentals=0)
         b, a = pl.pallas_call(
             _mk_kernel(off_rows, bm),

@@ -1,23 +1,25 @@
-"""ELLPACK min-plus relaxation — the Pallas TPU kernel for SSSP-Del's hot loop.
+"""ELLPACK min-plus relaxation — a Pallas kernel for SSSP-Del's hot loop.
 
-TPU adaptation (see DESIGN.md §2): GPU implementations scatter-min with
-atomics over CSR; TPUs have no atomics and hate irregular scatters, so we
-re-block the graph into sliced-ELLPACK — per destination row, a padded dense
-list of (in-neighbor, weight).  One wave is then:
+TPU adaptation (see DESIGN.md §2.7): GPU implementations scatter-min with
+atomics over CSR; TPUs have no atomics and hate irregular scatters, so the
+graph is re-blocked into ELLPACK — per destination row, a padded dense list
+of (in-neighbor, weight).  One wave is then:
 
-    gather (VMEM-resident dist tile) -> add -> row-min / row-argmin
+    gather (XLA) -> add -> row-min / row-argmin (kernel)
 
-entirely dense, VPU-friendly work.  Grid tiles rows in ``bm`` blocks; the
-dist vector is kept whole in VMEM (per-shard vertex counts at production
-scale are <= ~64k, i.e. <= 256 KiB f32 — trivially VMEM resident; the
-BlockSpec pins it once and Mosaic hoists the load out of the grid loop).
+The gather of the offered distances runs in XLA before the kernel: Mosaic
+lowers only 2-D gathers, so ``jnp.take`` on a whole-vector VMEM block is
+refused ("Only 2D gather is supported"), and a whole [N] block would not
+fit VMEM at deployment N anyway.  The kernel tiles rows in ``bm`` blocks of
+the gathered offers, the neighbor ids and the weights, and writes lane-dense
+``(1, R)`` outputs (a 1-D ``(bm,)`` block does not match the tiling XLA
+gives a 1-D array on TPU).  This kernel compiles for TPU v5e; it is used
+only when ``ell_use_kernel=True`` (DESIGN.md §2.7).
 
 Layout notes
 ------------
-* ``nbr_idx``/``nbr_w`` tiles are (bm, K): K is the slice's padded degree,
-  rounded to a multiple of 128 (lane width) by the host builder.
+* ``nbr_idx``/``nbr_w`` tiles are (bm, K): K is the slice's padded degree.
 * padded entries carry w=+inf, idx=0 — they can never win the min.
-* argmin is computed in-kernel with broadcasted_iota (TPU needs 2D iota).
 """
 from __future__ import annotations
 
@@ -30,19 +32,17 @@ from jax.experimental import pallas as pl
 from repro.kernels.relax.config import resolve_interpret
 
 
-def _relax_kernel(dist_ref, idx_ref, w_ref, best_ref, arg_ref):
-    dist = dist_ref[...]                       # (N,) VMEM-resident tile
+def _relax_kernel(gath_ref, idx_ref, w_ref, best_ref, arg_ref):
     idx = idx_ref[...]                         # (bm, K)
-    w = w_ref[...]                             # (bm, K)
-    cand = jnp.take(dist, idx, axis=0) + w     # dense gather + add
+    cand = gath_ref[...] + w_ref[...]          # (bm, K) offers + weights
     best = jnp.min(cand, axis=1)               # (bm,)
     # row-argmin with ties broken toward the SMALLEST NEIGHBOR ID — the same
     # rule the segment_min engine path uses, so both relaxation backends pick
     # bit-identical parents.  (min over masked ids; no iota/argmin needed.)
     is_min = cand == best[:, None]
     arg = jnp.min(jnp.where(is_min, idx, jnp.int32(2**31 - 1)), axis=1)
-    best_ref[...] = best
-    arg_ref[...] = jnp.where(jnp.isfinite(best), arg, -1).astype(jnp.int32)
+    best_ref[...] = best[None, :]
+    arg_ref[...] = jnp.where(jnp.isfinite(best), arg, -1)[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
@@ -58,25 +58,24 @@ def ellpack_relax(dist: jax.Array, nbr_idx: jax.Array, nbr_w: jax.Array,
     """
     interpret = resolve_interpret(interpret)
     R, K = nbr_idx.shape
-    N = dist.shape[0]
     bm = min(block_rows, R)
     assert R % bm == 0, (R, bm)
-    grid = (R // bm,)
-    return pl.pallas_call(
+    # the gather runs in XLA: Mosaic lowers only 2-D gathers, and a
+    # whole-vector dist block would not fit VMEM at deployment N anyway
+    gath = jnp.take(dist, nbr_idx, axis=0)
+    # lane-dense (1, R) outputs: a 1-D (bm,) block does not match the
+    # 1024-element tiling XLA gives a 1-D array on TPU
+    tile = pl.BlockSpec((bm, K), lambda i: (i, 0))
+    row = pl.BlockSpec((1, bm), lambda i: (0, i))
+    best, arg = pl.pallas_call(
         _relax_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((N,), lambda i: (0,)),              # dist: whole vector
-            pl.BlockSpec((bm, K), lambda i: (i, 0)),          # idx tile
-            pl.BlockSpec((bm, K), lambda i: (i, 0)),          # w tile
-        ],
-        out_specs=[
-            pl.BlockSpec((bm,), lambda i: (i,)),
-            pl.BlockSpec((bm,), lambda i: (i,)),
-        ],
+        grid=(R // bm,),
+        in_specs=[tile, tile, tile],
+        out_specs=[row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((R,), jnp.float32),
-            jax.ShapeDtypeStruct((R,), jnp.int32),
+            jax.ShapeDtypeStruct((1, R), jnp.float32),
+            jax.ShapeDtypeStruct((1, R), jnp.int32),
         ],
         interpret=interpret,
-    )(dist, nbr_idx, nbr_w)
+    )(gath, nbr_idx, nbr_w)
+    return best[0], arg[0]

@@ -7,17 +7,10 @@ forces 512 host devices while tests/benches run on the single real device.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 names explicit/auto axis kinds
-    from jax.sharding import AxisType
-except ImportError:  # older jaxlib: every axis is Auto already
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _mk(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(
         shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
