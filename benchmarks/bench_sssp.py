@@ -748,126 +748,6 @@ def serving(sink: C.CsvSink, small: bool) -> None:
               identical=True)
 
 
-def obs_overhead(sink: C.CsvSink, small: bool) -> None:
-    """Observability overhead contract (DESIGN.md §10.4): the same
-    power-law stream ingested with the telemetry layer off and on, passes
-    interleaved so scheduler drift hits both variants equally — on the
-    single-device engine HERE and on the sharded engine over 8 forced
-    host devices in a subprocess (benchmarks/obs_worker.py; XLA_FLAGS
-    must precede jax init).  The instrumented passes run with a
-    default-threshold watchdog armed, which must stay silent (§10.8).
-    In-run asserts pin the §10 invariants — identical (dist, parent)
-    trees and bit-identical rounds/messages via ``metrics_snapshot()``
-    (counters must not perturb the computation), every span count equal
-    to its engine counter, and histogram totals equal to the flat
-    counters (§10.6).  The regression gate (benchmarks/
-    check_regression.py) holds instrumented throughput at >= 0.95x
-    uninstrumented on BOTH legs."""
-    import jax
-    from repro.graphs import generators as gen
-    from repro.obs import WatchdogConfig
-
-    n = (1 << 10) if small else (1 << 11)
-    m = 4 * n
-    nv, src, dst, w = gen.power_law_hubs(n, m, n_hubs=4, seed=31,
-                                         orientation="in")
-    source = int(gen.top_in_degree_sources(nv, dst)[0])
-    log = C.stream_for(C.Dataset("plaw", nv, src, dst, w,
-                                 np.asarray([source])),
-                       window_frac=1 / 3, delta=0.3,
-                       query_every=max(1, len(src) // 12))
-
-    def mk(obs_on):
-        return SSSPDelEngine(EngineConfig(
-            num_vertices=nv, edge_capacity=m + 64, source=source,
-            relax_backend="sliced", observability=obs_on,
-            # default thresholds: only multi-second stalls fire — this
-            # gated bench doubles as the watchdog-stays-silent check
-            obs_watchdog=WatchdogConfig() if obs_on else None))
-
-    best = {False: 0.0, True: 0.0}
-    final = {}
-    for _ in range(3):                      # 1 warm + best-of-2 timed
-        for obs_on in (False, True):        # interleaved passes
-            eng = mk(obs_on)
-            t0 = time.perf_counter()
-            eng.ingest_log(log)
-            jax.block_until_ready(eng.state.sssp.dist)
-            eps = len(log) / (time.perf_counter() - t0)
-            if eps > best[obs_on]:
-                best[obs_on], final[obs_on] = eps, eng
-    for obs_on in (False, True):
-        eng = final[obs_on]
-        snap = eng.metrics_snapshot()
-        sink.emit("obs_overhead", dataset="plaw", n=nv, edges=m,
-                  backend="sliced", engine="single", observability=obs_on,
-                  events=len(log), events_per_s=round(best[obs_on], 1),
-                  epochs=eng.n_epochs, rounds=snap["rounds"],
-                  messages=snap["messages"],
-                  spans=sum(snap["spans"].values()),
-                  **(C.hist_fields(snap) if obs_on else {}))
-
-    # §10 invariants on the benchmark stream: telemetry must be free of
-    # algorithmic effect and internally consistent
-    q_off, q_on = final[False].query(), final[True].query()
-    np.testing.assert_array_equal(q_off.dist, q_on.dist)
-    np.testing.assert_array_equal(q_off.parent, q_on.parent)
-    on = final[True]
-    snap = on.metrics_snapshot()
-    assert int(snap["rounds"]) == int(on.n_rounds)
-    assert int(snap["messages"]) == int(on.n_messages)
-    assert int(final[False].n_rounds) == int(on.n_rounds)
-    sp, ct = snap["spans"], snap["counters"]
-    for kind, name in (("add_epoch", "add_epochs"),
-                       ("del_epoch", "del_epochs"),
-                       ("drain", "drains"), ("query", "queries")):
-        assert sp.get(kind, 0) == ct.get(name, 0), (kind, sp, ct)
-    # histogram totals == flat counters (§10.6) and a silent watchdog
-    # on a healthy gated run (§10.8)
-    h = snap["histograms"]
-    assert h["latency_us"]["count"] == ct["queries"]
-    assert h["frontier_occupancy"]["count"] == ct["add_epochs"]
-    assert h["waves_per_epoch"]["count"] == \
-        ct["add_epochs"] + ct["del_epochs"]
-    assert "watchdog_warnings" not in ct, ct.get("watchdog_warnings")
-    _check_oracle(on, sink, "obs_overhead_oracle")
-    sink.emit("obs_overhead_summary", backend="sliced", engine="single",
-              on_vs_off=round(best[True] / max(best[False], 1e-9), 3),
-              identical=True)
-
-    # ---- sharded leg.  On the CPU: P=8 forced host devices in a fresh
-    # process (the XLA device-count flag must precede jax init); the
-    # worker runs the same interleaved on/off protocol + in-run §10
-    # asserts and emits OBSROW json lines this section re-emits for the
-    # regression gate.  On a TPU: the same leg in this process over the
-    # real devices — a child cannot reach the chip this process holds.
-    import json
-    import os
-    import subprocess
-    import sys
-
-    from repro.kernels.relax import config as kernel_config
-    if kernel_config.on_tpu():
-        from benchmarks import obs_worker
-        for rec in obs_worker.run(small):
-            sink.emit(rec.pop("bench"), **rec)
-        return
-    cmd = [sys.executable, "-m", "benchmarks.obs_worker"]
-    if small:
-        cmd.append("--small")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in ("src", env.get("PYTHONPATH", "")) if p)
-    out = subprocess.run(cmd, capture_output=True, text=True, env=env)
-    assert out.returncode == 0, (
-        f"obs_worker failed:\n{out.stderr[-3000:]}")
-    for line in out.stdout.splitlines():
-        if line.startswith("OBSROW "):
-            rec = json.loads(line[len("OBSROW "):])
-            sink.emit(rec.pop("bench"), **rec)
-
-
 def scale(sink: C.CsvSink, small: bool) -> None:
     """Paper-scale ingest trajectory (DESIGN.md §11): synthetic N-vertex /
     10N-edge ADD streams synthesized and ingested chunk-by-chunk, one
@@ -1039,4 +919,4 @@ def sparse_frontier(sink: C.CsvSink, small: bool) -> None:
 ALL = [table2_static_baseline, fig1_query_latency, fig2_latency_over_time,
        fig3_source_selection, fig4_stability, fig5_throughput,
        fig6_batch_bsp, backend_shootout, hub_shootout, bucket_shootout,
-       dist_engine, serving, obs_overhead, scale, sparse_frontier]
+       dist_engine, serving, scale, sparse_frontier]

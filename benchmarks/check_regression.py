@@ -30,11 +30,6 @@ Gates (per delta value found in the section):
     layout, one fused epoch per batch instead of S), with the per-lane
     bit-parity record (``serving_summary.identical``) present and true and
     the latency/stability metric fields present on every batched row.
-  * obs_overhead — instrumented ingest (observability on) must hold
-    >= 0.95x the uninstrumented throughput on the same stream (DESIGN.md
-    §10.4: lazy device counters + host-side spans stay out of the epoch
-    path), with the bit-identity record (``obs_overhead_summary.identical``)
-    present and true.
   * bucket_shootout — the lazy bucketed schedule must hold >= 2.0x the
     eager rounds schedule's events/s on the delta=0.5 ER stream for every
     backend (DESIGN.md §9: the round tax), with the final-state parity
@@ -62,8 +57,7 @@ import json
 import sys
 
 DEFAULT_SECTIONS = ("backend_shootout", "dist_engine", "hub_shootout",
-                    "bucket_shootout", "serving", "obs_overhead", "scale",
-                    "sparse_frontier")
+                    "bucket_shootout", "serving", "scale", "sparse_frontier")
 
 # absolute floor for the scale section's chunked ingest (events/s): local
 # runs measure 150k-350k across N=64k..1M; CI's shared 2-core runners are
@@ -262,45 +256,6 @@ def gate_bucket_shootout(records: list[dict]) -> list[str]:
     return errors
 
 
-def gate_obs_overhead(records: list[dict]) -> list[str]:
-    errors: list[str] = []
-    rows = _rows(records, "obs_overhead")
-    summaries = _rows(records, "obs_overhead_summary")
-    if not rows or not summaries:
-        return ["obs_overhead: no records found"]
-    # two legs since the sharded P=8 worker landed: the single-device
-    # engine and the sharded engine each carry their own interleaved
-    # on/off pair and must each hold the 0.95x floor (DESIGN.md §10.4)
-    engines = sorted({str(r.get("engine", "single")) for r in rows})
-    for leg in ("single", "sharded"):
-        if leg not in engines:
-            errors.append(f"obs_overhead: missing {leg}-engine leg")
-    for leg in engines:
-        by = _by([r for r in rows
-                  if str(r.get("engine", "single")) == leg],
-                 "observability")
-        if (True,) not in by or (False,) not in by:
-            errors.append(f"obs_overhead[{leg}]: missing on/off pair")
-            continue
-        # instrumented ingest must stay within 5% of uninstrumented; the
-        # rounds/messages bit-identity itself is asserted in-run
-        ratio = _ratio_gate(errors, f"obs_overhead[{leg}] on/off ingest",
-                            float(by[(True,)]["events_per_s"]),
-                            float(by[(False,)]["events_per_s"]),
-                            floor=0.95)
-        print(f"obs_overhead[{leg}]: instrumented/uninstrumented ingest "
-              f"{ratio:.2f}x")
-    for s in summaries:
-        if str(s.get("identical")) != "True":
-            errors.append(f"obs_overhead: bit-identity record missing or "
-                          f"false: identical={s.get('identical')}")
-    sum_engines = {str(s.get("engine", "single")) for s in summaries}
-    for leg in ("single", "sharded"):
-        if leg not in sum_engines:
-            errors.append(f"obs_overhead: missing {leg} summary record")
-    return errors
-
-
 def gate_scale(records: list[dict]) -> list[str]:
     errors: list[str] = []
     rows = _rows(records, "scale")
@@ -369,7 +324,6 @@ GATES = {
     "bucket_shootout": gate_bucket_shootout,
     "dist_engine": gate_dist_engine,
     "hub_shootout": gate_hub_shootout,
-    "obs_overhead": gate_obs_overhead,
     "serving": gate_serving,
 }
 

@@ -3,11 +3,9 @@
 Sections:
   * SSSP-Del paper tables/figures (benchmarks/bench_sssp.py) with Dijkstra
     oracle cross-checks — one function per paper table/figure — plus the
-    beyond-paper sections: backend_shootout, hub_shootout, dist_engine,
-    ``serving`` (batched multi-source trace replay with the
-    latency/stability/throughput record, DESIGN.md §8) and
-    ``obs_overhead`` (the §10.4 observability overhead contract:
-    instrumented vs uninstrumented ingest on the same stream);
+    beyond-paper sections: backend_shootout, hub_shootout, dist_engine
+    and ``serving`` (batched multi-source trace replay with the
+    latency/stability/throughput record, DESIGN.md §8);
   * kernel micro-benchmarks (Pallas interpret-mode vs jnp reference);
   * roofline table distilled from the dry-run reports (if reports/ exists).
 

@@ -332,21 +332,23 @@ class ShardedSSSPDelEngine(StreamEngineBase):
 
     # ------------------------------------------------------------------ adds
     def _ingest_adds(self, batch: ev.EventBatch) -> None:
-        src, dst, w = batch.src, batch.dst, batch.w
-        if self.perm is not None:
-            src, dst = self.perm[src], self.perm[dst]
-        owner = np.asarray(dst, np.int64) // self.npp
-        parts, plans = [], []
-        for p in np.unique(owner):
-            sel = owner == p
-            plan = self.allocs[p].plan_adds(src[sel], dst[sel], w[sel])
-            if len(plan.slots):
-                plans.append((int(p), plan))
-                parts.append((int(p) * self.epp + plan.slots.astype(np.int64),
-                              plan.src, plan.dst, plan.w))
-        if not parts:
-            return
-        gslot, bsrc, bdst, bw = (np.concatenate(x) for x in zip(*parts))
+        with self.obs.tracer.span("plan_adds"):
+            src, dst, w = batch.src, batch.dst, batch.w
+            if self.perm is not None:
+                src, dst = self.perm[src], self.perm[dst]
+            owner = np.asarray(dst, np.int64) // self.npp
+            parts, plans = [], []
+            for p in np.unique(owner):
+                sel = owner == p
+                plan = self.allocs[p].plan_adds(src[sel], dst[sel], w[sel])
+                if len(plan.slots):
+                    plans.append((int(p), plan))
+                    parts.append((int(p) * self.epp
+                                  + plan.slots.astype(np.int64),
+                                  plan.src, plan.dst, plan.w))
+            if not parts:
+                return
+            gslot, bsrc, bdst, bw = (np.concatenate(x) for x in zip(*parts))
         n_acc = len(gslot)
         with self.obs.epoch("add_epoch", events=n_acc):
             self.bk.stage_adds(plans)  # layout patches (or coupled rebuild)
@@ -403,20 +405,23 @@ class ShardedSSSPDelEngine(StreamEngineBase):
     # ------------------------------------------------------------------ dels
     def _ingest_dels(self, batch: ev.EventBatch) -> None:
         for gsrc, gdst in self._deletion_groups(batch):
-            if self.perm is not None:
-                gsrc, gdst = self.perm[gsrc], self.perm[gdst]
-            owner = np.asarray(gdst, np.int64) // self.npp
-            parts = []
-            for p in np.unique(owner):
-                sel = owner == p
-                slots, psrc, pdst = self.allocs[p].plan_dels(
-                    gsrc[sel], gdst[sel])
-                if len(slots):
-                    parts.append((int(p) * self.epp + slots.astype(np.int64),
-                                  psrc, pdst))
+            with self.obs.tracer.span("plan_dels"):
+                if self.perm is not None:
+                    gsrc, gdst = self.perm[gsrc], self.perm[gdst]
+                owner = np.asarray(gdst, np.int64) // self.npp
+                parts = []
+                for p in np.unique(owner):
+                    sel = owner == p
+                    slots, psrc, pdst = self.allocs[p].plan_dels(
+                        gsrc[sel], gdst[sel])
+                    if len(slots):
+                        parts.append((int(p) * self.epp
+                                      + slots.astype(np.int64), psrc, pdst))
+                if parts:
+                    gslot, psrc, pdst = (np.concatenate(x)
+                                         for x in zip(*parts))
             if not parts:
                 continue
-            gslot, psrc, pdst = (np.concatenate(x) for x in zip(*parts))
             n_del = len(gslot)
             with self.obs.epoch("del_epoch", events=n_del):
                 if self.obs.enabled:
@@ -434,6 +439,7 @@ class ShardedSSSPDelEngine(StreamEngineBase):
                 # deletion, and deletions are per-event in the
                 # paper-faithful mode
                 n_mut = len(type(self.bk).del_mutated)
+                r0 = self._dev_rounds
                 if self.bucketed:
                     # invalidation-only epoch: seed + mark + SetToInfinity +
                     # tombstone; the recompute pull and push waves are
@@ -459,6 +465,10 @@ class ShardedSSSPDelEngine(StreamEngineBase):
                     if n_mut:
                         self.bk.update_del_arrays(out[3:3 + n_mut])
                     self._dev_rounds, self._dev_messages = out[3 + n_mut:]
+                # the epoch returned the cumulative counter: its share
+                # is this epoch's DEL rounds (one dispatch)
+                self._dev_del_rounds = _add_delta(
+                    self._dev_del_rounds, self._dev_rounds, r0)
                 if self.obs.enabled:
                     self._fold_epoch_obs()
                 self.n_dels += n_del
@@ -496,15 +506,17 @@ class ShardedSSSPDelEngine(StreamEngineBase):
                 self.obs.counters.add("drain_waves", self._dev_rounds - r0)
                 self._fold_epoch_obs()
 
-    def _snapshot(self, lane: int | None) -> tuple[np.ndarray, np.ndarray]:
-        """Sharded device->host readback plus the inverse relabeling, if
-        any (latency is timed by the base query()); a routed lane query
-        transfers only that source's padded [N] pair."""
+    def _device_pair(self, lane: int | None) -> tuple[jax.Array, jax.Array]:
+        """The sharded pair the base query() settles and reads back; a
+        routed lane query transfers only that source's padded [N] pair."""
         self.drain()
-        d, p = (self.dist, self.parent) if lane is None else \
+        return (self.dist, self.parent) if lane is None else \
             (self.dist[lane], self.parent[lane])
-        dist = np.asarray(jax.device_get(d))
-        parent = np.asarray(jax.device_get(p))
+
+    def _host_view(self, dist: np.ndarray, parent: np.ndarray
+                   ) -> tuple[np.ndarray, np.ndarray]:
+        """The read-back pair in the caller's vertex ids: the inverse
+        relabeling, if any, and the padding rows cut off."""
         n = self.cfg.num_vertices
         if self.perm is not None:
             dist = dist[..., self.perm]
@@ -585,6 +597,12 @@ class ShardedSSSPDelEngine(StreamEngineBase):
     def partition_fill(self) -> np.ndarray:
         """Live edges per partition, from the host mirrors (no device sync)."""
         return np.array([int(a.mactive.sum()) for a in self.allocs])
+
+
+@jax.jit
+def _add_delta(acc, new, old):
+    """``acc + (new - old)`` in one dispatch."""
+    return acc + (new - old)
 
 
 def _build_epochs(ds: DistributedSSSP, epp: int, use_doubling: bool,

@@ -29,10 +29,10 @@ Beyond-paper switches:
     ``delete`` hooks and never branches on the backend name.
 
 Host-sync rules (DESIGN.md §2.4): the ingest loop never blocks on device
-values.  Round/message stats accumulate in device scalars and are only read
-back inside ``query()``; deletion epochs run unconditionally (an all-false
-seed is a cheap device no-op) instead of the old ``bool(jnp.any(seed))``
-round-trip per deletion.
+values.  Round/message stats accumulate in device scalars (DEL rounds also
+apart, ``rounds_by_kind``) and are only read back inside ``query()``;
+deletion epochs run unconditionally (an all-false seed is a cheap device
+no-op) instead of the old ``bool(jnp.any(seed))`` round-trip per deletion.
 """
 from __future__ import annotations
 
@@ -93,9 +93,10 @@ class EngineConfig:
     frontier_kernel: bool = False   # Pallas gathered-rows wave kernel
     # batched multi-source serving (DESIGN.md §8); None = single-source
     sources: tuple[int, ...] | None = None
-    # observability (DESIGN.md §10): device-side counter registry + span
-    # tracer + flight recorder; off by default — the obs_overhead bench +
-    # check_regression gate hold instrumented ingest >= 0.95x uninstrumented
+    # observability (DESIGN.md §10): named spans are always on; this turns
+    # on the opt-in record — device-side counter registry, span list and
+    # exports, histograms, flight recorder.  Off by default; its cost is
+    # measured on the chip (DESIGN.md §10.4)
     observability: bool = False
     obs_flight_capacity: int = 128
     # stall/divergence watchdog (§10.8): a WatchdogConfig arms it (only
@@ -218,7 +219,8 @@ class SSSPDelEngine(StreamEngineBase):
 
     # ------------------------------------------------------------------ adds
     def _ingest_adds(self, batch: ev.EventBatch) -> None:
-        plan = self.alloc.plan_adds(batch.src, batch.dst, batch.w)
+        with self.obs.tracer.span("plan_adds"):
+            plan = self.alloc.plan_adds(batch.src, batch.dst, batch.w)
         if len(plan.slots) == 0:
             return
         with self.obs.epoch("add_epoch", events=len(plan.slots)):
@@ -299,7 +301,8 @@ class SSSPDelEngine(StreamEngineBase):
     # ------------------------------------------------------------------ dels
     def _ingest_dels(self, batch: ev.EventBatch) -> None:
         for gsrc, gdst in self._deletion_groups(batch):
-            slots, psrc, pdst = self.alloc.plan_dels(gsrc, gdst)
+            with self.obs.tracer.span("plan_dels"):
+                slots, psrc, pdst = self.alloc.plan_dels(gsrc, gdst)
             if len(slots) == 0:
                 continue
             with self.obs.epoch("del_epoch", events=len(slots)):
@@ -412,15 +415,13 @@ class SSSPDelEngine(StreamEngineBase):
                 # waves this drain spent (the §9 bucket pacing figure)
                 self.obs.counters.add("drain_waves", stats.rounds)
 
-    def _snapshot(self, lane: int | None) -> tuple[np.ndarray, np.ndarray]:
-        """Device->host readback (latency is timed by the base query());
-        a routed lane query transfers only that source's [N] pair."""
+    def _device_pair(self, lane: int | None) -> tuple[jax.Array, jax.Array]:
+        """The pair the base query() settles and reads back; a routed lane
+        query transfers only that source's [N] pair."""
         self.drain()
         s = self.state.sssp
-        dist, parent = (s.dist, s.parent) if lane is None else \
+        return (s.dist, s.parent) if lane is None else \
             (s.dist[lane], s.parent[lane])
-        return (np.asarray(jax.device_get(dist)),
-                np.asarray(jax.device_get(parent)))
 
     # ------------------------------------------------------------ checkpoint
     def checkpoint(self) -> dict[str, np.ndarray]:

@@ -8,9 +8,10 @@ that is *stream* logic rather than *epoch* logic lives here:
   * the driver loop (``ingest_log``) that coalesces the log into runs and
     dispatches ADD/DEL batches and QUERY markers;
   * the ``QueryResult`` record returned at every QUERY marker, with its
-    wall-clock ``latency_s`` timed HERE (the template ``query()`` wraps the
-    engine's ``_snapshot`` readback) so both engines measure result latency
-    identically — the serving harness's latency metric (DESIGN.md §8);
+    wall-clock ``latency_s`` timed HERE (the template ``query()`` settles
+    the dispatched epochs, then reads back the engine's ``_device_pair``)
+    so both engines measure result latency identically — the serving
+    harness's latency metric (DESIGN.md §8);
   * multi-source lane routing (DESIGN.md §8): engines constructed with
     ``sources=(s0, s1, ...)`` maintain stacked ``[S, N]`` dist/parent state;
     ``query(source=s)`` reads back ONE lane, ``query()`` the full stack,
@@ -18,12 +19,14 @@ that is *stream* logic rather than *epoch* logic lives here:
   * lazy device-scalar stats counters (DESIGN.md §2.4: the ingest loop never
     blocks on a device value — rounds/messages accumulate on device and are
     only read back inside ``query()``; in batched mode they are ``[S]``
-    device vectors, one independent counter per source);
+    device vectors, one independent counter per source), with the DEL
+    epochs' share of the rounds kept apart (``rounds_by_kind``);
   * the paper's §5.4 predecessor-stability metric;
   * the device-scalar stat accumulators the epoch results fold into.
 
-Subclasses implement ``_ingest_adds`` / ``_ingest_dels`` / ``_snapshot`` and
-keep ``_dev_rounds`` / ``_dev_messages`` as device scalars (or ``[S]``
+Subclasses implement ``_ingest_adds`` / ``_ingest_dels`` / ``_device_pair``
+(and ``_host_view`` where host ids differ) and keep ``_dev_rounds`` /
+``_dev_del_rounds`` / ``_dev_messages`` as device scalars (or ``[S]``
 vectors).  Layout-specific work lives one layer down, behind the
 ``RelaxBackend`` protocol (core/backends/, DESIGN.md §7): the single-device
 engine folds its backend's epoch stats through ``_accumulate_relax`` /
@@ -45,11 +48,23 @@ from repro.obs import EngineObs, WatchdogConfig
 from repro.obs import hist as hist_mod
 
 
+@jax.jit
+def _fold_delete(rounds, del_rounds, messages, dstats):
+    """One dispatch folding a deletion epoch's ``DeleteStats`` into the
+    cumulative rounds, DEL rounds and messages; also returns the epoch's
+    own rounds and messages (the histogram samples)."""
+    r = dstats.invalidation_rounds + dstats.recompute_rounds
+    m = dstats.recompute_messages + dstats.affected
+    return rounds + r, del_rounds + r, messages + m, r, m
+
+
 @dataclasses.dataclass
 class QueryResult:
     dist: np.ndarray      # f32[N] (lane or single-source) or f32[S, N]
     parent: np.ndarray    # i32 of the same shape
-    latency_s: float      # wall-clock snapshot latency (timed in query())
+    # wall-clock query latency (timed in query()): the wait for the
+    # dispatched epochs (any pending drain included), then the readback
+    latency_s: float
     epoch_stats: dict[str, Any]
     source: int | None = None   # the lane's source for a routed query
 
@@ -83,13 +98,15 @@ class StreamEngineBase:
         self.n_adds = 0
         self.n_dels = 0
         # round/message counters live ON DEVICE; read back lazily at query()
-        # (batched engines keep one independent [S] counter per source)
+        # (batched engines keep one independent [S] counter per source);
+        # _dev_del_rounds is the DEL epochs' share of _dev_rounds
         if self.sources is not None:
             self._dev_rounds = jnp.zeros((len(self.sources),), jnp.int32)
             self._dev_messages = jnp.zeros((len(self.sources),), jnp.int32)
         else:
             self._dev_rounds = jnp.int32(0)
             self._dev_messages = jnp.int32(0)
+        self._dev_del_rounds = self._dev_rounds
         # previous parent snapshot per stability scope (None = full state,
         # a source id = that routed lane) — two routed [N] snapshots from
         # DIFFERENT lanes must never be compared against each other
@@ -109,6 +126,17 @@ class StreamEngineBase:
     @property
     def n_messages(self) -> int | np.ndarray:
         return self._counter(self._dev_messages)
+
+    @property
+    def rounds_by_kind(self) -> dict[str, int | np.ndarray]:
+        """``n_rounds`` split by epoch kind, summing to it exactly:
+        ``del`` counts the invalidation and recompute rounds of DEL
+        epochs, ``add`` every other round (ADD relax epochs, and the
+        drains of the bucketed schedule, which also settle what its
+        deletions left pending).  Per source when batched."""
+        total, dels = jax.device_get((self._dev_rounds, self._dev_del_rounds))
+        return {"add": self._counter(total - dels),
+                "del": self._counter(dels)}
 
     def _stream_stats(self) -> dict[str, Any]:
         return {
@@ -133,12 +161,13 @@ class StreamEngineBase:
 
     def _accumulate_delete(self, dstats) -> None:
         """Fold one deletion epoch's ``DeleteStats`` into the device
-        scalars; ``affected`` counts as messages (the SetToInfinity
-        deliveries), matching the sharded epochs' accounting."""
-        rounds = dstats.invalidation_rounds + dstats.recompute_rounds
-        messages = dstats.recompute_messages + dstats.affected
-        self._dev_rounds = self._dev_rounds + rounds
-        self._dev_messages = self._dev_messages + messages
+        scalars, the DEL rounds among them, in one dispatch; ``affected``
+        counts as messages (the SetToInfinity deliveries), matching the
+        sharded epochs' accounting."""
+        (self._dev_rounds, self._dev_del_rounds, self._dev_messages,
+         rounds, messages) = _fold_delete(
+            self._dev_rounds, self._dev_del_rounds, self._dev_messages,
+            dstats)
         if self.obs.enabled:
             self.obs.hist_device("hist_waves_per_epoch", rounds)
             self.obs.hist_device("hist_messages_per_epoch", messages)
@@ -161,10 +190,16 @@ class StreamEngineBase:
     def _ingest_dels(self, batch: ev.EventBatch) -> None:
         raise NotImplementedError
 
-    def _snapshot(self, lane: int | None) -> tuple[np.ndarray, np.ndarray]:
-        """Device->host readback of (dist, parent) — one lane of the
-        stacked state when ``lane`` is given, everything otherwise."""
+    def _device_pair(self, lane: int | None) -> tuple[jax.Array, jax.Array]:
+        """The (dist, parent) device arrays a query returns — one lane of
+        the stacked state when ``lane`` is given, everything otherwise —
+        after settling any deferred work (the bucketed drain)."""
         raise NotImplementedError
+
+    def _host_view(self, dist: np.ndarray, parent: np.ndarray
+                   ) -> tuple[np.ndarray, np.ndarray]:
+        """The read-back pair in the caller's vertex ids (identity here)."""
+        return dist, parent
 
     def _obs_pre_snapshot(self) -> None:
         """Engine-specific lazy folds right before the registry snapshot
@@ -202,9 +237,11 @@ class StreamEngineBase:
         return self._lane_of[source]
 
     def query(self, source: int | None = None) -> QueryResult:
-        """State collection (paper §3): epochs are already enforced (every
-        batch runs to convergence), so the query cost is the device->host
-        readback — timed here as the result latency (DESIGN.md §8).
+        """State collection (paper §3): every batch was dispatched to run
+        to convergence, so the query waits for the dispatched epochs (the
+        ``settle`` span; a bucketed engine drains first), then reads the
+        pair back (``readback``) — the whole timed here as the result
+        latency (DESIGN.md §8).
 
         ``source`` routes the query to one maintained tree of a batched
         engine (only that lane is read back); a single-source engine
@@ -219,10 +256,16 @@ class StreamEngineBase:
                     f"source {source} is not served by this engine "
                     f"(source={self.cfg.source})")
         t0 = time.perf_counter()
-        # the query span NESTS any drain span _snapshot dispatches — the
+        # the query span NESTS any drain span _device_pair dispatches — the
         # bucketed engines settle pending work inside the query (§10.2)
         with self.obs.epoch("query", lane=lane):
-            dist, parent = self._snapshot(lane)
+            dist, parent = self._device_pair(lane)
+            with self.obs.tracer.span("settle"):
+                jax.block_until_ready((dist, parent))
+            with self.obs.tracer.span("readback"):
+                dist = np.asarray(jax.device_get(dist))
+                parent = np.asarray(jax.device_get(parent))
+            dist, parent = self._host_view(dist, parent)
         dt = time.perf_counter() - t0
         if self.obs.enabled:
             # result-latency histogram in microseconds (§10.6): total
@@ -287,7 +330,9 @@ class StreamEngineBase:
         device_get), span counts, and flight-recorder occupancy.  Consumed
         by ``ServingReport``, both examples, the exporters (§10.7) and the
         benches.  An armed watchdog reviews the snapshot for divergence;
-        its findings land in the *next* snapshot's counters (§10.8)."""
+        its findings land in the *next* snapshot's counters (§10.8).
+        ``span_totals`` is the tracer's always-on per-name aggregate,
+        and ``rounds_by_kind`` splits ``rounds`` by epoch kind."""
         if self.obs.enabled:
             self._obs_pre_snapshot()
             self.obs.flush_histograms()
@@ -295,11 +340,13 @@ class StreamEngineBase:
         snap = {
             "epochs": self.n_epochs, "adds": self.n_adds,
             "dels": self.n_dels, "rounds": self.n_rounds,
+            "rounds_by_kind": self.rounds_by_kind,
             "messages": self.n_messages,
             "counters": counters,
             "histograms": hist_mod.summarize(counters),
             "attribution": self.obs.counters.attribution(counters),
             "spans": self.obs.tracer.span_counts(),
+            "span_totals": self.obs.tracer.totals(),
             "flight": {"records": self.obs.recorder.total,
                        "capacity": self.obs.recorder.capacity},
         }

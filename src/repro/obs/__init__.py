@@ -3,13 +3,19 @@ registry, span tracing with Perfetto export, and a per-epoch flight
 recorder — shared by both engines via ``StreamEngineBase``.
 
 ``EngineObs`` bundles the three pieces behind one facade the engines
-drive:
+drive.  Two parts of it are always on, enabled or not: named spans
+(``SPAN_NAMES``), each a ``jax.profiler.TraceAnnotation`` on the
+profiler's clock plus a fixed per-name aggregate (``tracer.totals()``:
+count, total ns, self ns).  Everything else below is the opt-in record
+(``enabled=True``):
 
   * ``with obs.epoch(kind, **attrs):`` wraps one dispatched epoch — it
-    opens a tracer span (plus the jax.profiler TraceAnnotation), bumps
-    the matching host counter (``add_epoch`` -> ``add_epochs``), appends
-    a flight-recorder record with the dispatch wall time, and on an
-    escaping exception dumps the flight recorder ONCE before re-raising.
+    opens a tracer span, and when enabled bumps the matching host
+    counter (``add_epoch`` -> ``add_epochs``), appends a flight-recorder
+    record with the dispatch wall time, and on an escaping exception
+    dumps the flight recorder ONCE before re-raising.
+  * ``with obs.tracer.span(name):`` wraps a region that is not an epoch
+    (slot planning, a query's settle and readback): a span only.
   * ``obs.note_layout(totals)`` diffs the backend's monotone layout
     totals (``RelaxBackend.layout_counters()``: rebuilds, overflow-lane
     hits) against the last observation, folding the deltas into counters
@@ -34,9 +40,9 @@ drive:
     the one-shot dump from a sampler thread, slow-epoch/frontier
     thresholds are checked synchronously after each epoch.
 
-Disabled (the default) every hook no-ops; the ``obs_overhead`` bench +
-``check_regression`` gate hold instrumented ingest >= 0.95x
-uninstrumented (§10.4).
+Disabled (the default) every hook but the spans no-ops.  What the spans
+and the opt-in record cost is measured on the chip, end to end, by the
+benchmark in ``bench/`` (DESIGN.md §10.4).
 """
 from __future__ import annotations
 
@@ -49,12 +55,12 @@ from typing import Any, Iterator
 from repro.obs import hist as hist_mod
 from repro.obs.counters import CounterRegistry
 from repro.obs.recorder import FlightRecorder
-from repro.obs.spans import (Span, SpanTracer, load_chrome_trace,
-                             span_counts_of)
+from repro.obs.spans import (SPAN_NAMES, Span, SpanTracer,
+                             load_chrome_trace, span_counts_of)
 from repro.obs.watchdog import Watchdog, WatchdogConfig
 
 __all__ = [
-    "CounterRegistry", "EngineObs", "FlightRecorder", "Span", "SpanTracer",
+    "SPAN_NAMES", "CounterRegistry", "EngineObs", "FlightRecorder", "Span", "SpanTracer",
     "Watchdog", "WatchdogConfig", "load_chrome_trace", "out_path_or_exit",
     "span_counts_of", "write_log_jsonl",
 ]
@@ -91,7 +97,8 @@ class EngineObs:
     @contextmanager
     def epoch(self, kind: str, **attrs) -> Iterator[None]:
         if not self.enabled:
-            yield
+            with self.tracer.span(kind):
+                yield
             return
         wd = self.watchdog
         t0 = time.perf_counter()

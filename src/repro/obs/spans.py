@@ -1,16 +1,21 @@
 """Span tracer (DESIGN.md §10.2): monotonic host-side spans over the
-engines' epoch dispatches, exportable as Chrome trace-event JSON (loads
+engines' layer boundaries, exportable as Chrome trace-event JSON (loads
 directly in Perfetto / chrome://tracing) and as JSONL.
 
-A span wraps one host-side dispatch region — add/del epoch, drain,
-checkpoint, query — with ``time.perf_counter_ns`` stamps; when
-``jax.profiler`` is importable each span also opens a
-``TraceAnnotation`` so the same names land in XLA profiler traces.
-Instant events mark point occurrences (layout rebuilds).  Nothing here
-touches device values: the tracer is pure host bookkeeping, so it obeys
-the §2.4 no-host-sync rule by construction (the device work inside a
-span stays async; the span measures dispatch wall time, which is the
-quantity the ingest loop actually spends).
+A span wraps one host-side region — slot planning, add/del epoch
+dispatch, drain, checkpoint, query and its settle and readback (the
+names in ``SPAN_NAMES``) — with ``time.perf_counter_ns`` stamps.  Every
+span, enabled or not, opens a ``jax.profiler.TraceAnnotation`` under its
+own name, so it lands in profiler traces on the device planes' clock,
+and folds its duration into a fixed per-name aggregate (``totals()``:
+count, total ns, self ns).  Only an enabled tracer also keeps the
+``Span`` list the exports read, so a disabled one holds memory that does
+not grow with the stream.  Instant events mark point occurrences
+(layout rebuilds).  Nothing here touches device values: the tracer is
+pure host bookkeeping, so it obeys the §2.4 no-host-sync rule by
+construction (the device work inside a span stays async; a span around
+a dispatch measures dispatch wall time, the quantity the ingest loop
+actually spends).
 """
 from __future__ import annotations
 
@@ -25,7 +30,13 @@ try:  # TraceAnnotation exists across our supported jax range; stay soft
 except Exception:  # pragma: no cover - profiler missing from a slim build
     _TraceAnnotation = None
 
-__all__ = ["Span", "SpanTracer", "load_chrome_trace", "span_counts_of"]
+__all__ = ["SPAN_NAMES", "Span", "SpanTracer", "load_chrome_trace",
+           "span_counts_of"]
+
+# every span the engines open, so a reader of a profiler trace can tell
+# program spans from the runtime's own host events
+SPAN_NAMES = ("plan_adds", "plan_dels", "add_epoch", "del_epoch", "drain",
+              "query", "settle", "readback", "checkpoint")
 
 
 @dataclasses.dataclass
@@ -44,16 +55,16 @@ class SpanTracer:
         self._annotate = (_TraceAnnotation is not None if annotate is None
                           else bool(annotate) and _TraceAnnotation is not None)
         self._base_ns = time.perf_counter_ns()
-        self._depth = 0
-        self.spans: list[Span] = []   # completion order
+        # per open span, the ns its finished child spans covered
+        self._child_ns: list[int] = []
+        # name -> [count, total ns, self ns]; one entry per span name
+        self._totals: dict[str, list[int]] = {}
+        self.spans: list[Span] = []   # completion order; enabled only
 
     @contextmanager
     def span(self, name: str, **args) -> Iterator[None]:
-        if not self.enabled:
-            yield
-            return
-        depth = self._depth
-        self._depth += 1
+        depth = len(self._child_ns)
+        self._child_ns.append(0)
         ann = _TraceAnnotation(name) if self._annotate else None
         t0 = time.perf_counter_ns()
         try:
@@ -63,15 +74,22 @@ class SpanTracer:
             else:
                 yield
         finally:
-            self._depth = depth
-            self.spans.append(Span(name, t0, time.perf_counter_ns() - t0,
-                                   depth, "X", args))
+            dur = time.perf_counter_ns() - t0
+            child = self._child_ns.pop()
+            if self._child_ns:
+                self._child_ns[-1] += dur
+            agg = self._totals.setdefault(name, [0, 0, 0])
+            agg[0] += 1
+            agg[1] += dur
+            agg[2] += dur - child
+            if self.enabled:
+                self.spans.append(Span(name, t0, dur, depth, "X", args))
 
     def instant(self, name: str, **args) -> None:
         if not self.enabled:
             return
         self.spans.append(Span(name, time.perf_counter_ns(), 0,
-                               self._depth, "i", args))
+                               len(self._child_ns), "i", args))
 
     # --------------------------------------------------------------- readout
     def span_counts(self) -> dict[str, int]:
@@ -81,6 +99,13 @@ class SpanTracer:
         for s in self.spans:
             counts[s.name] = counts.get(s.name, 0) + 1
         return counts
+
+    def totals(self) -> dict[str, dict[str, int]]:
+        """Completed spans by name, enabled or not: ``count``,
+        ``total_ns`` and ``self_ns`` (duration less the part its child
+        spans cover)."""
+        return {name: {"count": c, "total_ns": t, "self_ns": s}
+                for name, (c, t, s) in self._totals.items()}
 
     # --------------------------------------------------------------- exports
     def to_chrome(self) -> dict[str, Any]:

@@ -116,6 +116,7 @@ def test_sharded_engine_joins_the_equivalence_contract(backend):
     np.testing.assert_array_equal(q_seg.parent, q_shd.parent)
     assert seg.n_rounds == shd.n_rounds
     assert seg.n_messages == shd.n_messages
+    assert seg.rounds_by_kind == shd.rounds_by_kind
 
 
 @pytest.mark.parametrize("backend", ["segment", "ellpack", "sliced"])

@@ -30,9 +30,9 @@ from repro.core import events as ev
 from repro.core.dist_engine import ShardedEngineConfig, ShardedSSSPDelEngine
 from repro.core.engine import EngineConfig, SSSPDelEngine
 from repro.graphs import generators, window
-from repro.obs import (CounterRegistry, EngineObs, FlightRecorder,
-                       SpanTracer, load_chrome_trace, out_path_or_exit,
-                       span_counts_of, write_log_jsonl)
+from repro.obs import (SPAN_NAMES, CounterRegistry, EngineObs,
+                       FlightRecorder, SpanTracer, load_chrome_trace,
+                       out_path_or_exit, span_counts_of, write_log_jsonl)
 from repro.serving import TraceRecorder, replay_trace
 
 HERE = os.path.dirname(__file__)
@@ -155,6 +155,52 @@ def test_disabled_tracer_records_nothing():
     assert tr.spans == [] and tr.span_counts() == {}
 
 
+@pytest.mark.parametrize("enabled", [False, True])
+def test_span_totals_fold_count_total_and_self_time(enabled, monkeypatch):
+    """The always-on aggregate: per name, count, total ns, and self ns —
+    the duration less what the span's children cover (grandchildren are
+    inside the children, so they are not subtracted twice)."""
+    import types
+
+    import repro.obs.spans as spans_mod
+    clock = iter(range(0, 10_000, 10))      # each reading 10 ns later
+    monkeypatch.setattr(spans_mod, "time", types.SimpleNamespace(
+        perf_counter_ns=lambda: next(clock)))
+    tr = SpanTracer(enabled=enabled, annotate=False)   # reads 0
+    with tr.span("query"):                  # 10 .. 80
+        with tr.span("settle"):             # 20 .. 50
+            with tr.span("drain"):          # 30 .. 40
+                pass
+        with tr.span("readback"):           # 60 .. 70
+            pass
+    with tr.span("readback"):               # 90 .. 100
+        pass
+    assert tr.totals() == {
+        "drain": {"count": 1, "total_ns": 10, "self_ns": 10},
+        "settle": {"count": 1, "total_ns": 30, "self_ns": 20},
+        "readback": {"count": 2, "total_ns": 20, "self_ns": 20},
+        "query": {"count": 1, "total_ns": 70, "self_ns": 30},
+    }
+    assert len(tr.spans) == (5 if enabled else 0)
+    assert tr.span_counts() == (
+        {"query": 1, "settle": 1, "drain": 1, "readback": 2}
+        if enabled else {})
+
+
+def test_span_totals_count_a_span_left_by_an_exception():
+    tr = SpanTracer(enabled=False)
+    with pytest.raises(RuntimeError):
+        with tr.span("del_epoch"):
+            raise RuntimeError("boom")
+    with tr.span("add_epoch"):
+        pass
+    assert {k: v["count"] for k, v in tr.totals().items()} == \
+        {"del_epoch": 1, "add_epoch": 1}
+    # the failed span left no open frame behind: add_epoch is top-level
+    assert tr.totals()["add_epoch"]["self_ns"] == \
+        tr.totals()["add_epoch"]["total_ns"]
+
+
 # ----------------------------------------------------------- flight recorder --
 def test_flight_recorder_ring_wraps_at_capacity():
     fr = FlightRecorder(capacity=8)
@@ -204,6 +250,17 @@ def test_engine_obs_epoch_dumps_flight_recorder_once(capsys):
         with obs.epoch("drain"):
             raise RuntimeError("again")
     assert "flight recorder" not in capsys.readouterr().err
+
+
+def test_engine_obs_disabled_keeps_only_span_totals():
+    obs = EngineObs(enabled=False)
+    with obs.epoch("add_epoch"):
+        with obs.tracer.span("plan_adds"):
+            pass
+    assert obs.tracer.spans == [] and obs.recorder.total == 0
+    assert obs.counters.snapshot() == {}
+    assert {k: v["count"] for k, v in obs.tracer.totals().items()} == \
+        {"add_epoch": 1, "plan_adds": 1}
 
 
 def test_engine_obs_disabled_is_inert():
@@ -338,28 +395,115 @@ def test_dump_flight_recorder_postmortem():
         EngineConfig(n, m + 64, 3, obs_flight_capacity=0)
 
 
+# ------------------------------------------------ always-on spans, rounds --
+@pytest.mark.parametrize("engine", ["single", "sharded"])
+@pytest.mark.parametrize("schedule", ["rounds", "buckets"])
+def test_spans_stay_on_with_observability_off(engine, schedule):
+    """With observability off the tracer keeps no Span list, but its
+    aggregate holds every span the path opened, counted once per epoch,
+    planning call, deletion group and query."""
+    n, m, log = _dynamic_stream(seed=41)
+    eng = _mk(engine, "segment", n, m + 64, 3, wave_schedule=schedule)
+    res = eng.ingest_log(log)
+    assert eng.obs.tracer.spans == []
+    tot = eng.obs.tracer.totals()
+    count = {k: v["count"] for k, v in tot.items()}
+    opened = {"plan_adds", "plan_dels", "add_epoch", "del_epoch", "query",
+              "settle", "readback"} | ({"drain"} if schedule == "buckets"
+                                       else set())
+    assert set(count) == opened and opened <= set(SPAN_NAMES)
+    runs = list(log.runs())
+    assert count["plan_adds"] == sum(b.kind == ev.ADD for b in runs)
+    assert count["plan_dels"] == sum(
+        len(eng._deletion_groups(b)) for b in runs if b.kind == ev.DEL)
+    assert count["add_epoch"] + count["del_epoch"] == eng.n_epochs
+    assert count["query"] == count["settle"] == count["readback"] \
+        == len(res)
+    if schedule == "buckets":
+        assert count["drain"] == len(res)
+    for name, t in tot.items():
+        assert 0 <= t["self_ns"] <= t["total_ns"], name
+    # the drain, settle and readback are children of the query
+    q = tot["query"]
+    assert q["total_ns"] - q["self_ns"] >= \
+        tot["settle"]["total_ns"] + tot["readback"]["total_ns"]
+    snap = eng.metrics_snapshot()
+    assert snap["spans"] == {} and snap["span_totals"] == tot
+
+
+def test_span_totals_do_not_grow_with_the_stream():
+    n, m, log = _dynamic_stream(seed=43)
+    eng = _mk("single", "segment", n, m + 64, 3)
+    eng.ingest_log(log)
+    first = eng.obs.tracer.totals()
+    for _ in range(2):
+        eng.ingest_log(log)
+    again = eng.obs.tracer.totals()
+    assert eng.obs.tracer.spans == [] and eng.obs.tracer._child_ns == []
+    assert set(again) == set(first)
+    # a replayed ADD is a duplicate (planned, never dispatched): planning
+    # and query spans triple, epoch spans need not
+    for name in ("plan_adds", "plan_dels", "query", "settle", "readback"):
+        assert again[name]["count"] == 3 * first[name]["count"]
+    assert eng.obs.recorder.total == 0
+
+
+@pytest.mark.parametrize("backend", ["segment", "ellpack", "sliced"])
+@pytest.mark.parametrize("schedule", ["rounds", "buckets"])
+def test_rounds_by_kind_sum_to_n_rounds_in_both_engines(backend, schedule):
+    """DEL rounds apart from the rest, summing to ``n_rounds`` exactly, and
+    the same split in the single-device and the sharded engine."""
+    n, m, log = _dynamic_stream(seed=47)
+    got = {}
+    for engine in ("single", "sharded"):
+        eng = _mk(engine, backend, n, m + 64, 3, wave_schedule=schedule)
+        eng.ingest_log(log)
+        eng.query()
+        kinds = eng.rounds_by_kind
+        assert kinds["add"] + kinds["del"] == eng.n_rounds
+        assert kinds["add"] > 0 and kinds["del"] > 0
+        assert eng.metrics_snapshot()["rounds_by_kind"] == kinds
+        got[engine] = kinds
+    assert got["single"] == got["sharded"]
+
+
+def test_rounds_by_kind_are_per_lane_when_batched():
+    n, m, log = _dynamic_stream(seed=53)
+    eng = SSSPDelEngine(EngineConfig(n, m + 64, 3, sources=(3, 17, 40)))
+    eng.ingest_log(log)
+    kinds = eng.rounds_by_kind
+    assert kinds["add"].shape == kinds["del"].shape == (3,)
+    np.testing.assert_array_equal(kinds["add"] + kinds["del"], eng.n_rounds)
+
+
 # -------------------------------------------------- §2.4 no-host-sync rule --
+@pytest.mark.parametrize("observability", [False, True])
 @pytest.mark.parametrize("engine", ["single", "sharded"])
 @pytest.mark.parametrize("backend", ["segment", "ellpack", "sliced"])
 def test_instrumented_ingest_never_reads_device_values(engine, backend,
+                                                       observability,
                                                        monkeypatch):
-    """Satellite: the device_get trap holds WITH observability enabled —
-    the counter registry accumulates lazily, spans are pure host
-    bookkeeping, so ADD/DEL ingest still never syncs."""
+    """Satellite: the device_get trap holds with the always-on spans and
+    the DEL-round counter, and WITH observability enabled — the counter
+    registry accumulates lazily, spans are pure host bookkeeping, so
+    ADD/DEL ingest still never syncs nor waits for the device."""
     n, m, log = _dynamic_stream(seed=13)
-    eng = _mk(engine, backend, n, m + 64, 0, observability=True)
+    eng = _mk(engine, backend, n, m + 64, 0, observability=observability)
     topo = log[np.asarray(log.kind) != ev.QUERY]
 
     def trap(*a, **k):
         raise AssertionError("device_get during instrumented ingest")
 
     monkeypatch.setattr(jax, "device_get", trap)
+    monkeypatch.setattr(jax, "block_until_ready", trap)
     eng.ingest_log(topo)  # only ADD/DEL runs: must not sync
     monkeypatch.undo()
     q = eng.query()
     assert np.isfinite(np.asarray(q.dist)).any()
     snap = eng.metrics_snapshot()   # the sanctioned read-back point
-    assert snap["counters"]["add_epochs"] > 0
+    assert snap["span_totals"]["add_epoch"]["count"] > 0
+    if observability:
+        assert snap["counters"]["add_epochs"] > 0
 
 
 # ----------------------------------------------------------- CLI / examples --

@@ -11,8 +11,7 @@ Contracts under test:
     is still blocked;
   * divergence review — a waves-per-epoch histogram whose top occupied
     bucket reaches ``max_drain_waves`` is flagged at most once;
-  * a default-config watchdog stays silent on a healthy engine run (the
-    property the gated obs_overhead benches rely on).
+  * a default-config watchdog stays silent on a healthy engine run.
 """
 import time
 
