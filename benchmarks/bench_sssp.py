@@ -812,10 +812,10 @@ def sparse_frontier(sink: C.CsvSink, small: bool) -> None:
         from the checksum-cached SNAP download instead of synthetic RMAT
         (graphs/datasets.fetch_dataset; CI stays synthetic).
       * **auto-high-occupancy** — a delta=0.5 sliding-window ER stream
-        whose cascades blow past every ladder rung: ``frontier_mode=
-        "auto"`` must route these epochs dense from the host-side
-        occupancy bound and stay >= 0.95x the dense engine's throughput
-        (the routing-overhead gate)."""
+        whose cascades blow past every ladder rung: the default
+        ``frontier_mode="auto"`` falls back dense on the device wave by
+        wave and must stay >= 0.95x the dense engine's throughput (the
+        routing-overhead gate)."""
     import os
 
     import jax
@@ -838,7 +838,7 @@ def sparse_frontier(sink: C.CsvSink, small: bool) -> None:
 
     def run_localized(n: int, mode: str, batches: list) -> tuple:
         bs, bd, bw = localized_base(n)
-        kw = {} if mode == "dense" else dict(frontier_mode=mode)
+        kw = dict(frontier_mode=mode)
         eng = SSSPDelEngine(EngineConfig(
             num_vertices=n, edge_capacity=len(bs) + 8 * len(batches) + 64,
             source=0, **kw))
@@ -892,7 +892,7 @@ def sparse_frontier(sink: C.CsvSink, small: bool) -> None:
         window_frac=1 / 3, delta=0.5, query_every=10**9)
     times, engines = {}, {}
     for mode in ("dense", "auto"):
-        kw = {} if mode == "dense" else dict(frontier_mode="auto")
+        kw = dict(frontier_mode=mode)
         for _timed in (False, True):   # first pass warms every jit shape
             eng = SSSPDelEngine(EngineConfig(
                 num_vertices=nv, edge_capacity=m + 64, source=source, **kw))

@@ -17,9 +17,9 @@ one epoch (``batch_deletions=True``, DESIGN.md §3).
 
 One chip runs two phases through ``repro.make_engine``:
 
-  phase 2  the default engine (segment backend, rounds schedule, dense
-           frontier); its final query is also checked against the
-           Dijkstra oracle (``core/oracle.py``);
+  phase 2  the default engine (segment backend, rounds schedule,
+           frontier-compacted waves); its final query is also checked
+           against the Dijkstra oracle (``core/oracle.py``);
   phase 3  ``relax_backend="sliced"`` on ``wave_schedule="buckets"``, the
            hub-aware layout built for power-law graphs (DESIGN.md §6/§9).
 

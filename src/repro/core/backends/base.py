@@ -78,6 +78,23 @@ WAVE_SCHEDULES = ("rounds", "buckets")
 FRONTIER_MODES = ("dense", "sparse", "auto")
 
 
+def ladder_route(cfg: Any) -> bool:
+    """Whether the single-device engine runs its push waves through the
+    frontier-compacted capacity ladder (DESIGN.md §12.3).  ``"dense"`` is
+    the reference and ``"sparse"`` forces the ladder everywhere; the
+    default ``"auto"`` takes it where it pays and nothing is lost: one
+    source (under ``vmap`` a ``lax.cond`` runs both branches), the rounds
+    schedule and the segment backend, whose dense round is the ladder's
+    own fallback.  Which waves then run compacted is decided on the
+    device, per wave."""
+    mode = getattr(cfg, "frontier_mode", "dense")
+    if mode != "auto":
+        return mode == "sparse"
+    return (getattr(cfg, "sources", None) is None
+            and getattr(cfg, "wave_schedule", "rounds") == "rounds"
+            and getattr(cfg, "relax_backend", "segment") == "segment")
+
+
 def validate_backend_config(cfg: Any) -> None:
     """Raise ``ValueError`` at construction time for an unknown
     ``relax_backend`` or backend knobs that don't apply to the selected

@@ -500,6 +500,7 @@ class SlicedPlan(NamedTuple):
     osrc: np.ndarray   # i32[s]
     orows: np.ndarray  # i32[s]
     ow: np.ndarray     # f32[s]
+    at: np.ndarray     # i64[m] per input edge: its cell, or cells + entry
 
 
 class SlicedEllPlanner:
@@ -575,7 +576,8 @@ class SlicedEllPlanner:
         z32 = np.empty(0, np.int32)
         zf = np.empty(0, np.float32)
         if m == 0:
-            return SlicedPlan(z32, z32, z32, z32, zf, z32, z32, z32, zf)
+            return SlicedPlan(z32, z32, z32, z32, zf, z32, z32, z32, zf,
+                              np.empty(0, np.int64))
         rows = np.asarray(rows, np.int64) - self.row0
         kcand = self.fill[rows] + rank_within_rows(rows)
         to_ell = kcand < self.rowk[rows]
@@ -595,12 +597,15 @@ class SlicedEllPlanner:
         opos = (self.ofill + sp_rank[over]).astype(np.int32)
         self.ofill += n_spill
         self.spills += n_spill
+        pos = (self.base[erows] + ekpos).astype(np.int32)
+        at = np.empty(m, np.int64)
+        at[to_ell] = pos
+        at[over] = self.cells + opos
         return SlicedPlan(
-            pos=(self.base[erows] + ekpos).astype(np.int32),
-            rows=erows.astype(np.int32), kpos=ekpos,
+            pos=pos, rows=erows.astype(np.int32), kpos=ekpos,
             src=np.asarray(src)[to_ell], w=np.asarray(w)[to_ell],
             opos=opos, osrc=np.asarray(src)[over],
-            orows=rows[over].astype(np.int32), ow=np.asarray(w)[over])
+            orows=rows[over].astype(np.int32), ow=np.asarray(w)[over], at=at)
 
     def required_geometry(self, dst: np.ndarray
                           ) -> tuple[list[int], int]:
@@ -620,22 +625,25 @@ class SlicedEllPlanner:
         ocap = max(self.ocap, _next_pow2(max(2 * surplus, 8)))
         return widths, ocap
 
-    def rebuild_host(self, src: np.ndarray, dst: np.ndarray, w: np.ndarray):
+    def rebuild_host(self, src: np.ndarray, dst: np.ndarray, w: np.ndarray,
+                     positions: bool = False):
         """Numpy half of ``rebuild`` — the sharded coordinator concatenates
         these blocks partition-major before one sharded transfer.  Returns
         (flat_idx, flat_w, fill, osrc, odst, ow) with ``odst`` in the
-        planner's local row space."""
+        planner's local row space; ``positions=True`` appends each input
+        edge's position (``sliced_ell_from_coo``'s ``at``)."""
         self.widths, self.ocap = self.required_geometry(dst)
-        flat_idx, flat_w, fill, _, osrc, odst, ow, n_over = \
+        flat_idx, flat_w, fill, _, osrc, odst, ow, n_over, *at = \
             csr_mod.sliced_ell_from_coo(
                 self.n, src, dst, w, slice_rows=self.sr, hub_k=self.hub_k,
                 n_rows=self.rows, widths=self.widths,
-                overflow_capacity=self.ocap, row0=self.row0)
+                overflow_capacity=self.ocap, row0=self.row0,
+                positions=positions)
         self.fill = fill
         self.ofill = n_over
         self.rebuilds += 1
         self._recompute_geometry()
-        return flat_idx, flat_w, fill, osrc, odst, ow
+        return (flat_idx, flat_w, fill, osrc, odst, ow, *at)
 
     def rebuild(self, src: np.ndarray, dst: np.ndarray, w: np.ndarray
                 ) -> SlicedEllState:

@@ -199,11 +199,12 @@ def run_drain(dist: jax.Array, parent: jax.Array, pend: PendingState,
     limit is one broadcast scalar (the sharded drain computes it from the
     already-allgathered offers: no new collectives).
 
-    ``track_occupancy=True`` (the frontier-compacted sparse drain,
-    DESIGN.md §12) additionally folds each wave's active-vertex count into a
-    fourth returned i32 device scalar — the ``frontier_occupancy`` obs
-    signal per §2.4; the extra carry slot rides at 0 otherwise and the
-    3-tuple return shape is preserved for existing callers.
+    ``track_occupancy=True`` (the frontier-compacted drain, DESIGN.md §12)
+    is the ladder's accounting, as in ``relax.converged_loop``: ``wave``
+    returns a fourth value (1 when it ran compacted), and the drain also
+    returns the summed active-vertex counts (the ``frontier_occupancy``
+    obs signal per §2.4) and the compacted waves; the 3-tuple return shape
+    is kept for the other callers.
     """
     any_pull = jnp.any(pend.pull)
 
@@ -222,24 +223,28 @@ def run_drain(dist: jax.Array, parent: jax.Array, pend: PendingState,
     msgs0 = jnp.sum(imp.astype(jnp.int32))
 
     def cond(carry):
-        _, _, push, _, _, _ = carry
+        _, _, push, _, _, _, _ = carry
         return jnp.any(push)
 
     def body(carry):
-        dist, parent, push, rounds, msgs, occ = carry
+        dist, parent, push, rounds, msgs, occ, sparse = carry
         active = bucket_active(dist, push, bucket_width)
         if track_occupancy:
             occ = occ + jnp.sum(active.astype(jnp.int32))
-        dist, parent, improved = wave(dist, parent, active)
+            dist, parent, improved, took = wave(dist, parent, active)
+            sparse = sparse + took
+        else:
+            dist, parent, improved = wave(dist, parent, active)
         push = (push & ~active) | improved
         return (dist, parent, push, rounds + 1,
-                msgs + jnp.sum(improved.astype(jnp.int32)), occ)
+                msgs + jnp.sum(improved.astype(jnp.int32)), occ, sparse)
 
-    dist, parent, _, rounds, msgs, occ = jax.lax.while_loop(
-        cond, body, (dist, parent, push, rounds0, msgs0, jnp.int32(0)))
+    zero = jnp.int32(0)
+    dist, parent, _, rounds, msgs, occ, sparse = jax.lax.while_loop(
+        cond, body, (dist, parent, push, rounds0, msgs0, zero, zero))
     stats = RelaxStats(rounds=rounds, messages=msgs)
     if track_occupancy:
-        return dist, parent, stats, occ
+        return dist, parent, stats, occ, sparse
     return dist, parent, stats
 
 

@@ -28,11 +28,19 @@ Beyond-paper switches:
     calls the protocol's ``apply_adds`` / ``apply_dels`` / ``relax`` /
     ``delete`` hooks and never branches on the backend name.
 
+Frontier-compacted waves (DESIGN.md §12): on one source under the rounds
+schedule with the segment backend, every push wave runs through the
+capacity ladder (``core/frontier.py``), which the device steers per wave
+from the frontier it sees, and an ADD epoch's first wave relaxes the
+inserted edges alone.  ``frontier_mode="dense"`` keeps the dense reference;
+``"sparse"`` forces the ladder on every engine shape.
+
 Host-sync rules (DESIGN.md §2.4): the ingest loop never blocks on device
 values.  Round/message stats accumulate in device scalars (DEL rounds also
-apart, ``rounds_by_kind``) and are only read back inside ``query()``;
-deletion epochs run unconditionally (an all-false seed is a cheap device
-no-op) instead of the old ``bool(jnp.any(seed))`` round-trip per deletion.
+apart, ``rounds_by_kind``; each round by how it ran, ``rounds_by_route``)
+and are only read back inside ``query()``; deletion epochs run
+unconditionally (an all-false seed is a cheap device no-op) instead of the
+old ``bool(jnp.any(seed))`` round-trip per deletion.
 """
 from __future__ import annotations
 
@@ -49,12 +57,57 @@ from repro.core import events as ev
 from repro.core import frontier as frontier_mod
 from repro.core import ingest, relax
 from repro.core.backends import RELAX_BACKENDS
+from repro.core.backends.base import ladder_route
 from repro.core.state import EdgePool, GraphState, SSSPState
 from repro.core.stream import QueryResult, StreamEngineBase
 from repro.kernels.relax import config as kernel_config
 from repro.obs import WatchdogConfig
 
-__all__ = ["EngineConfig", "QueryResult", "SSSPDelEngine", "RELAX_BACKENDS"]
+__all__ = ["EngineConfig", "QueryResult", "SSSPDelEngine", "RELAX_BACKENDS",
+           "ROUTES"]
+
+# how a round ran: the ADD epoch's wave over its inserted edges, a compacted
+# ladder wave, a dense wave over the pool, a DEL epoch's invalidation round,
+# or its bulk pull (a drain's pull too)
+ROUTES = ("seed", "sparse", "dense", "invalidation", "pull")
+
+
+def _route_counts(*counts) -> jax.Array:
+    """Per-route counts (scalars, or [S] vectors) stacked on a last axis."""
+    return jnp.stack(jnp.broadcast_arrays(
+        *(jnp.asarray(c, jnp.int32) for c in counts)), axis=-1)
+
+
+@jax.jit
+def _fold_relax(rounds, messages, routes, stats, tally, pulled):
+    """One dispatch folding an ADD epoch's or a drain's ``RelaxStats`` into
+    the cumulative rounds, messages and route counts.  ``tally`` (a ladder
+    epoch's ``WaveTally``) says how many waves were seeds or compacted, the
+    rest ran dense; ``pulled`` is a drain's pending-pull mask, whose
+    non-emptiness cost the drain one pull round."""
+    seed = sparse = pull = 0
+    if tally is not None:
+        seed, sparse = tally.seed, tally.sparse
+    if pulled is not None:
+        pull = jnp.any(pulled, axis=-1).astype(jnp.int32)
+    inc = _route_counts(seed, sparse, stats.rounds - seed - sparse - pull,
+                        0, pull)
+    return rounds + stats.rounds, messages + stats.messages, routes + inc
+
+
+@jax.jit
+def _fold_delete(rounds, del_rounds, messages, routes, dstats, tally):
+    """One dispatch folding a deletion epoch's ``DeleteStats`` into the
+    cumulative rounds, DEL rounds, messages and route counts; also returns
+    the epoch's own rounds and messages (the histogram samples).  A
+    recompute that ran began with its one bulk pull."""
+    r = dstats.invalidation_rounds + dstats.recompute_rounds
+    m = dstats.recompute_messages + dstats.affected
+    pull = jnp.minimum(dstats.recompute_rounds, 1)
+    sparse = 0 if tally is None else tally.sparse
+    inc = _route_counts(0, sparse, dstats.recompute_rounds - pull - sparse,
+                        dstats.invalidation_rounds, pull)
+    return rounds + r, del_rounds + r, messages + m, routes + inc, r, m
 
 
 @dataclasses.dataclass
@@ -84,11 +137,12 @@ class EngineConfig:
     # delta; inf = one bucket (plain converge); "auto" picks a pow2-quantized
     # percentile of the live pool weights at drain time (DESIGN.md §9.5)
     bucket_width: float | str = 1.0
-    # frontier-compacted sparse epochs (DESIGN.md §12): "sparse" routes every
-    # push epoch through the compacted worklist path (the capacity ladder's
-    # dense fallback bounds the regression when occupancy blows up); "auto"
-    # routes per epoch from host-known occupancy bounds
-    frontier_mode: str = "dense"
+    # frontier-compacted waves (DESIGN.md §12): "auto" runs every push wave
+    # through the capacity ladder where that pays (one source, rounds
+    # schedule, segment backend: ``backends.ladder_route``) and dense
+    # elsewhere; "sparse" forces the ladder on every engine shape; "dense"
+    # is the reference
+    frontier_mode: str = "auto"
     frontier_cap: int = 0           # top ladder rung; 0 = derive (~N/64)
     frontier_kernel: bool = False   # Pallas gathered-rows wave kernel
     # batched multi-source serving (DESIGN.md §8); None = single-source
@@ -161,39 +215,81 @@ class SSSPDelEngine(StreamEngineBase):
         self._pend = buckets.empty_pending(
             cfg.num_vertices,
             None if self.sources is None else len(self.sources))
-        # frontier-compacted sparse path (DESIGN.md §12): OUT-adjacency
-        # sidecar + capacity ladder; maintained whenever the mode can route
-        # sparse so the routing decision stays a pure host policy choice
-        self._sparse = cfg.frontier_mode != "dense"
-        if self._sparse:
-            self._out = frontier_mod.OutAdjacency(cfg.num_vertices)
+        # frontier-compacted waves (DESIGN.md §12): OUT-adjacency sidecar +
+        # capacity ladder; the ADD epochs of one source under the rounds
+        # schedule start from a seed wave over the inserted edges
+        self._ladder = ladder_route(cfg)
+        self._seeded = (self._ladder and self.sources is None
+                        and not self.bucketed)
+        if self._ladder:
+            self._out = frontier_mod.OutAdjacency(cfg.num_vertices,
+                                                  cfg.edge_capacity)
             self._caps = frontier_mod.capacity_ladder(cfg.num_vertices,
                                                       cfg.frontier_cap)
-        # host-side upper bound on pending-push occupancy (the "auto" drain
-        # signal; reset per drain, pinned to N when a deletion's affected
-        # set is unknown host-side)
-        self._pend_bound = 0
+        # rounds by route (ROUTES), device-side like _dev_rounds
+        self._dev_routes = jnp.zeros(
+            (() if self.sources is None else (len(self.sources),))
+            + (len(ROUTES),), jnp.int32)
         # bucket_width="auto" resolution cache: (resolved width, live-edge
         # estimate at resolution) — re-resolved when the pool doubles/halves
         self._bw_cache: tuple[float, int] | None = None
 
-    # -------------------------------------------------- sparse/width policy
-    def _route_sparse(self, occupancy_bound: int) -> bool:
-        """Host-only routing: "sparse" always takes the compacted path (the
-        device-side ladder bounds blowup); "auto" takes it only when the
-        host-known occupancy upper bound fits the top rung — no device
-        readback either way (DESIGN.md §2.4/§12.3)."""
-        if not self._sparse:
-            return False
-        if self.cfg.frontier_mode == "sparse":
-            return True
-        return occupancy_bound <= self._caps[-1]
-
-    def _fold_occupancy(self, occ) -> None:
+    # ----------------------------------------------------------- counters
+    def _accumulate_relax(self, stats, tally=None, pulled=None) -> None:
+        """Fold one ADD epoch's or drain's ``RelaxStats`` (and a ladder
+        epoch's ``WaveTally``) into the device counters in one dispatch —
+        no host sync.  Batched epochs carry ``[S]`` stat vectors.  With obs
+        on, the same stats also record one sample each for the
+        waves/messages-per-epoch histograms (§10.6), and a ladder epoch its
+        ``frontier_occupancy``."""
+        self._dev_rounds, self._dev_messages, self._dev_routes = _fold_relax(
+            self._dev_rounds, self._dev_messages, self._dev_routes, stats,
+            tally, pulled)
         if self.obs.enabled:
+            self.obs.hist_device("hist_waves_per_epoch", stats.rounds)
+            self.obs.hist_device("hist_messages_per_epoch", stats.messages)
+            self._fold_occupancy(tally)
+
+    def _accumulate_delete(self, dstats, tally=None) -> None:
+        """Fold one deletion epoch's ``DeleteStats`` into the device
+        counters, the DEL rounds among them, in one dispatch; ``affected``
+        counts as messages (the SetToInfinity deliveries), matching the
+        sharded epochs' accounting."""
+        (self._dev_rounds, self._dev_del_rounds, self._dev_messages,
+         self._dev_routes, rounds, messages) = _fold_delete(
+            self._dev_rounds, self._dev_del_rounds, self._dev_messages,
+            self._dev_routes, dstats, tally)
+        if self.obs.enabled:
+            self.obs.hist_device("hist_waves_per_epoch", rounds)
+            self.obs.hist_device("hist_messages_per_epoch", messages)
+            self._fold_occupancy(tally)
+
+    def _fold_occupancy(self, tally) -> None:
+        if tally is not None:
+            occ = tally.occupancy
             self.obs.counters.add(
-                "frontier_occupancy",
-                occ if getattr(occ, "ndim", 0) == 0 else jnp.sum(occ))
+                "frontier_occupancy", occ if occ.ndim == 0 else jnp.sum(occ))
+
+    @property
+    def rounds_by_route(self) -> dict[str, int | np.ndarray]:
+        """``n_rounds`` split by how each round ran (``ROUTES``), summing
+        to it exactly: ``seed`` the ADD epochs' waves over their inserted
+        edges, ``sparse`` the waves a ladder rung ran compacted, ``dense``
+        every other wave over the pool, ``invalidation`` and ``pull`` the
+        DEL epochs' marking rounds and bulk pulls (and the drains' pulls).
+        Per source when batched."""
+        got = np.asarray(jax.device_get(self._dev_routes))
+        return {k: self._counter(got[..., i]) for i, k in enumerate(ROUTES)}
+
+    def _stream_stats(self) -> dict:
+        return {**super()._stream_stats(),
+                "rounds_by_route": self.rounds_by_route}
+
+    def metrics_snapshot(self) -> dict:
+        return {**super().metrics_snapshot(),
+                "rounds_by_route": self.rounds_by_route}
+
+    # ---------------------------------------------------------- width policy
 
     def _bucket_width(self) -> float:
         """Resolve ``bucket_width="auto"`` host-side: the pow2-quantized
@@ -226,19 +322,13 @@ class SSSPDelEngine(StreamEngineBase):
         with self.obs.epoch("add_epoch", events=len(plan.slots)):
             slots_p, src_p, dst_p, w_p = ingest.pad_pow2(
                 plan.slots, plan.src, plan.dst, plan.w)
+            src_d, dst_d, w_d = (jnp.asarray(a) for a in (src_p, dst_p, w_p))
             edges = ingest.apply_adds(self.state.edges, jnp.asarray(slots_p),
-                                      jnp.asarray(src_p), jnp.asarray(dst_p),
-                                      jnp.asarray(w_p))
-            # Frontier = tails of the inserted edges (paper Listing 3: tail
-            # offers its distance to the head).  Relaxing from the tails
-            # delivers exactly those offers (plus no-op re-offers along
-            # other out-edges).
-            frontier = relax.frontier_from_vertices(
-                jnp.asarray(plan.src), self.cfg.num_vertices)
+                                      src_d, dst_d, w_d)
             self.backend.apply_adds(plan, self.alloc)
-            if self._sparse:
-                # OUT-adjacency sidecar rides along with every layout patch
-                # so the per-epoch routing stays a free policy choice
+            if self._ladder:
+                # the OUT-adjacency sidecar rides along with every layout
+                # patch
                 self._out.apply_adds(plan, self.alloc)
             if self._auto and getattr(self.backend, "blowup", False):
                 self._fallback_to_sliced()
@@ -257,26 +347,42 @@ class SSSPDelEngine(StreamEngineBase):
                 if self.obs.watchdog is not None:
                     self.obs.watchdog.observe(
                         "add_epoch", 0.0, {"frontier": nf})
+            if self._seeded:
+                # the first wave relaxes the inserted edges alone, then the
+                # ladder loop continues from what they improved
+                sssp, stats, tally = frontier_mod.seeded_relax(
+                    self.state.sssp, edges, self._out.state, src_d, dst_d,
+                    w_d, num_vertices=self.cfg.num_vertices, caps=self._caps,
+                    use_kernel=self.cfg.frontier_kernel,
+                    interpret=self._interpret)
+                self.state = dataclasses.replace(self.state, edges=edges,
+                                                 sssp=sssp)
+                self._accumulate_relax(stats, tally)
+                self.n_adds += len(plan.slots)
+                self.n_epochs += 1
+                return
+            # Frontier = tails of the inserted edges (paper Listing 3: tail
+            # offers its distance to the head).  Relaxing from the tails
+            # delivers exactly those offers (plus no-op re-offers along
+            # other out-edges).
+            frontier = relax.frontier_from_vertices(src_d,
+                                                    self.cfg.num_vertices)
             if self.bucketed:
                 # deferred settle (DESIGN.md §9): record the push obligation
                 # and return — the drain delivers the offers bucket-by-bucket
                 self._pend = buckets.enqueue_push(self._pend, frontier,
                                                   self.state.sssp.dist)
-                self._pend_bound += len(np.unique(plan.src))
                 self.state = dataclasses.replace(self.state, edges=edges)
-            elif self._route_sparse(len(np.unique(plan.src))):
-                sp_fn = (frontier_mod.sparse_relax_until_converged
-                         if self.sources is None
-                         else frontier_mod.sparse_relax_batched)
-                sssp, stats, occ = sp_fn(
+            elif self._ladder:
+                # batched lanes, forced through the ladder
+                sssp, stats, tally = frontier_mod.sparse_relax_batched(
                     self.state.sssp, edges, self._out.state, frontier,
                     num_vertices=self.cfg.num_vertices, caps=self._caps,
                     use_kernel=self.cfg.frontier_kernel,
                     interpret=self._interpret)
                 self.state = dataclasses.replace(self.state, edges=edges,
                                                  sssp=sssp)
-                self._accumulate_relax(stats)
-                self._fold_occupancy(occ)
+                self._accumulate_relax(stats, tally)
             else:
                 relax_fn = (self.backend.relax if self.sources is None
                             else self.backend.relax_batched)
@@ -312,16 +418,13 @@ class SSSPDelEngine(StreamEngineBase):
                    pdst: np.ndarray) -> None:
         """One dispatched deletion epoch (one span, one flight record)."""
         slots_p, psrc_p, pdst_p = ingest.pad_pow2(slots, psrc, pdst)
-        if self._sparse:
-            self._out.apply_dels(psrc_p, pdst_p)
+        if self._ladder:
+            self._out.apply_dels(slots, psrc)
         if self.bucketed:
             # ONE fused dispatch: deactivate + seed + mark + invalidate,
             # recomputation deferred to the drain (DESIGN.md §9).  The
             # layout tombstones still stage as their own patch op.
             self.backend.apply_dels(pdst_p, psrc_p)
-            # the affected subtree's size is device-only knowledge; pin the
-            # pending bound to N so the "auto" drain routes dense
-            self._pend_bound = self.cfg.num_vertices
             fn = (buckets.lazy_delete if self.sources is None
                   else buckets.lazy_delete_batched)
             sssp, edges, self._pend, dstats = fn(
@@ -353,24 +456,24 @@ class SSSPDelEngine(StreamEngineBase):
         edges = ingest.apply_dels(self.state.edges, jnp.asarray(slots_p))
         self.backend.apply_dels(pdst_p, psrc_p)
         # Non-tree deletions (all-false seed) are a device no-op with
-        # zeroed stats — cheaper than syncing on bool(jnp.any(seed)).
-        # Sparse routing for DELs is mode="sparse" only: the affected
-        # region's size is device-only knowledge, so "auto" stays dense.
-        if self._sparse and self.cfg.frontier_mode == "sparse":
+        # zeroed stats — cheaper than syncing on bool(jnp.any(seed)).  On
+        # the ladder route the recompute's push waves run compacted
+        # wherever the device finds the affected region small enough.
+        tally = None
+        if self._ladder:
             sp_fn = (frontier_mod.sparse_invalidate_and_recompute
                      if self.sources is None
                      else frontier_mod.sparse_delete_batched)
-            sssp, dstats, occ = sp_fn(
+            sssp, dstats, tally = sp_fn(
                 self.state.sssp, edges, self._out.state, seed,
                 num_vertices=self.cfg.num_vertices, caps=self._caps,
                 use_doubling=self.cfg.use_doubling,
                 use_kernel=self.cfg.frontier_kernel,
                 interpret=self._interpret)
-            self._fold_occupancy(occ)
         else:
             sssp, dstats = delete_fn(self.state.sssp, edges, seed)
         self.state = dataclasses.replace(self.state, edges=edges, sssp=sssp)
-        self._accumulate_delete(dstats)
+        self._accumulate_delete(dstats, tally)
         self.n_dels += len(slots)
         self.n_epochs += 1
 
@@ -392,25 +495,24 @@ class SSSPDelEngine(StreamEngineBase):
             self.obs.counters.add("pending_pull", occ_pull, dim=occ_dim)
         with self.obs.epoch("drain"):
             bw = self._bucket_width()
-            if self._route_sparse(self._pend_bound):
+            pulled, tally = self._pend.pull, None
+            if self._ladder:
                 sp_fn = (frontier_mod.sparse_drain if self.sources is None
                          else frontier_mod.sparse_drain_batched)
-                sssp, self._pend, stats, occ = sp_fn(
+                sssp, self._pend, stats, tally = sp_fn(
                     self.state.sssp, self.state.edges, self._out.state,
                     self._pend, num_vertices=self.cfg.num_vertices,
                     caps=self._caps, bucket_width=bw,
                     use_kernel=self.cfg.frontier_kernel,
                     interpret=self._interpret)
-                self._fold_occupancy(occ)
             else:
                 drain_fn = (self.backend.drain if self.sources is None
                             else self.backend.drain_batched)
                 sssp, self._pend, stats = drain_fn(
                     self.state.sssp, self.state.edges, self._pend,
                     bucket_width=bw)
-            self._pend_bound = 0
             self.state = dataclasses.replace(self.state, sssp=sssp)
-            self._accumulate_relax(stats)
+            self._accumulate_relax(stats, tally, pulled)
             if self.obs.enabled:
                 # waves this drain spent (the §9 bucket pacing figure)
                 self.obs.counters.add("drain_waves", stats.rounds)
@@ -453,7 +555,7 @@ class SSSPDelEngine(StreamEngineBase):
             self.cfg.edge_capacity, self.cfg.on_duplicate,
             ckpt["src"], ckpt["dst"], ckpt["w"], ckpt["active"])
         self.backend.restore(self.alloc)
-        if self._sparse:
+        if self._ladder:
             self._out.restore(self.alloc)
         # the restore's layout rebuild is a real rebuild event (§10)
         self.obs.note_layout(self.backend.layout_counters())
@@ -461,4 +563,3 @@ class SSSPDelEngine(StreamEngineBase):
         self._pend = buckets.empty_pending(
             self.cfg.num_vertices,
             None if self.sources is None else len(self.sources))
-        self._pend_bound = 0

@@ -86,41 +86,43 @@ def converged_loop(dist: jax.Array, parent: jax.Array, frontier: jax.Array,
                    wave, *, max_rounds: int = 0,
                    track_occupancy: bool = False
                    ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array,
-                              jax.Array]:
+                              jax.Array, jax.Array]:
     """The shared wave-to-fixpoint driver: loop ``wave(dist, parent,
     frontier) -> (dist, parent, improved)`` while the frontier is non-empty,
     counting rounds and improvement messages exactly as the original dense
-    loop did.  Both the dense epochs here and the frontier-compacted sparse
-    epochs (core/frontier.py, DESIGN.md §12) run through this driver, so
-    their (rounds, messages) accounting matches by construction.
+    loop did.  Both the dense epochs here and the frontier-compacted epochs
+    (core/frontier.py, DESIGN.md §12) run through this loop, so their
+    (rounds, messages) accounting matches by construction.
 
-    ``track_occupancy=True`` additionally folds ``sum(frontier)`` per wave
-    into the returned occupancy scalar (device-side, no host sync — the
-    ``frontier_occupancy`` obs counter per §2.4); otherwise the occupancy
-    slot rides along at 0.  Returns (dist, parent, rounds, messages, occ).
+    ``track_occupancy=True`` is the ladder's accounting: ``wave`` returns a
+    fourth value, 1 when the wave ran compacted and 0 when it fell back
+    dense, and the loop sums those and ``sum(frontier)`` per wave
+    (device-side, no host sync — §2.4); otherwise both slots ride along at
+    0.  Returns (dist, parent, rounds, messages, occupancy, sparse waves).
     """
 
     def cond(carry):
-        _, _, frontier, rounds, _, _ = carry
+        _, _, frontier, rounds, _, _, _ = carry
         go = jnp.any(frontier)
         if max_rounds:
             go = go & (rounds < max_rounds)
         return go
 
     def body(carry):
-        dist, parent, frontier, rounds, msgs, occ = carry
+        dist, parent, frontier, rounds, msgs, occ, sparse = carry
         if track_occupancy:
             occ = occ + jnp.sum(frontier.astype(jnp.int32))
-        dist, parent, improved = wave(dist, parent, frontier)
+            dist, parent, improved, took = wave(dist, parent, frontier)
+            sparse = sparse + took
+        else:
+            dist, parent, improved = wave(dist, parent, frontier)
         return (dist, parent, improved, rounds + 1,
-                msgs + jnp.sum(improved.astype(jnp.int32)), occ)
+                msgs + jnp.sum(improved.astype(jnp.int32)), occ, sparse)
 
-    dist, parent, _, rounds, msgs, occ = jax.lax.while_loop(
-        cond,
-        body,
-        (dist, parent, frontier, jnp.int32(0), jnp.int32(0), jnp.int32(0)),
-    )
-    return dist, parent, rounds, msgs, occ
+    zero = jnp.int32(0)
+    dist, parent, _, rounds, msgs, occ, sparse = jax.lax.while_loop(
+        cond, body, (dist, parent, frontier, zero, zero, zero, zero))
+    return dist, parent, rounds, msgs, occ, sparse
 
 
 @partial(jax.jit, static_argnames=("num_vertices", "max_rounds"))
@@ -146,7 +148,7 @@ def relax_until_converged(
             tie_perm=tie_perm)
         return dist, parent, improved
 
-    dist, parent, rounds, msgs, _ = converged_loop(
+    dist, parent, rounds, msgs, _, _ = converged_loop(
         sssp.dist, sssp.parent, frontier, wave, max_rounds=max_rounds)
     return (
         SSSPState(dist=dist, parent=parent, source=sssp.source),

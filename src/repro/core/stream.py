@@ -21,17 +21,16 @@ that is *stream* logic rather than *epoch* logic lives here:
     only read back inside ``query()``; in batched mode they are ``[S]``
     device vectors, one independent counter per source), with the DEL
     epochs' share of the rounds kept apart (``rounds_by_kind``);
-  * the paper's §5.4 predecessor-stability metric;
-  * the device-scalar stat accumulators the epoch results fold into.
+  * the paper's §5.4 predecessor-stability metric.
 
 Subclasses implement ``_ingest_adds`` / ``_ingest_dels`` / ``_device_pair``
 (and ``_host_view`` where host ids differ) and keep ``_dev_rounds`` /
 ``_dev_del_rounds`` / ``_dev_messages`` as device scalars (or ``[S]``
 vectors).  Layout-specific work lives one layer down, behind the
 ``RelaxBackend`` protocol (core/backends/, DESIGN.md §7): the single-device
-engine folds its backend's epoch stats through ``_accumulate_relax`` /
-``_accumulate_delete``; the sharded engine threads the same counters
-through its shard_map epochs as replicated device scalars.
+engine folds its epochs' stats into these counters in one jitted dispatch
+per epoch; the sharded engine threads the same counters through its
+shard_map epochs as replicated device scalars.
 """
 from __future__ import annotations
 
@@ -46,16 +45,6 @@ import numpy as np
 from repro.core import events as ev
 from repro.obs import EngineObs, WatchdogConfig
 from repro.obs import hist as hist_mod
-
-
-@jax.jit
-def _fold_delete(rounds, del_rounds, messages, dstats):
-    """One dispatch folding a deletion epoch's ``DeleteStats`` into the
-    cumulative rounds, DEL rounds and messages; also returns the epoch's
-    own rounds and messages (the histogram samples)."""
-    r = dstats.invalidation_rounds + dstats.recompute_rounds
-    m = dstats.recompute_messages + dstats.affected
-    return rounds + r, del_rounds + r, messages + m, r, m
 
 
 @dataclasses.dataclass
@@ -144,33 +133,6 @@ class StreamEngineBase:
             "messages": self.n_messages, "adds": self.n_adds,
             "dels": self.n_dels,
         }
-
-    def _accumulate_relax(self, stats) -> None:
-        """Fold one relaxation epoch's ``RelaxStats`` into the device
-        scalars (lazy add — no host sync).  Batched epochs carry ``[S]``
-        stat vectors; the add broadcasts the initial scalar up.  With obs
-        on, the same stats also record one sample each for the
-        waves/messages-per-epoch histograms (§10.6) — a host list append,
-        materialized at snapshot flush; still no host sync and no extra
-        dispatch on the hot path."""
-        self._dev_rounds = self._dev_rounds + stats.rounds
-        self._dev_messages = self._dev_messages + stats.messages
-        if self.obs.enabled:
-            self.obs.hist_device("hist_waves_per_epoch", stats.rounds)
-            self.obs.hist_device("hist_messages_per_epoch", stats.messages)
-
-    def _accumulate_delete(self, dstats) -> None:
-        """Fold one deletion epoch's ``DeleteStats`` into the device
-        scalars, the DEL rounds among them, in one dispatch; ``affected``
-        counts as messages (the SetToInfinity deliveries), matching the
-        sharded epochs' accounting."""
-        (self._dev_rounds, self._dev_del_rounds, self._dev_messages,
-         rounds, messages) = _fold_delete(
-            self._dev_rounds, self._dev_del_rounds, self._dev_messages,
-            dstats)
-        if self.obs.enabled:
-            self.obs.hist_device("hist_waves_per_epoch", rounds)
-            self.obs.hist_device("hist_messages_per_epoch", messages)
 
     # ------------------------------------------------------------- interface
     def _deletion_groups(self, batch: ev.EventBatch

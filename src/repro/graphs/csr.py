@@ -122,7 +122,7 @@ def sliced_ell_from_coo(
     n: int, src: np.ndarray, dst: np.ndarray, w: np.ndarray, *,
     slice_rows: int = 256, hub_k: int = 32, n_rows: int | None = None,
     widths: list[int] | None = None, overflow_capacity: int | None = None,
-    row0: int = 0,
+    row0: int = 0, positions: bool = False,
 ):
     """Hub-aware hybrid layout: flat sliced-ELL + COO overflow (by dst).
 
@@ -150,14 +150,18 @@ def sliced_ell_from_coo(
     globally addressed (every value must fall in the window; the returned
     rows and overflow ``odst`` are window-local), ``src`` ids pass through
     untouched — cells always store global in-neighbor ids.
+
+    ``positions=True`` appends ``at i64[E]``: where each input edge landed,
+    its flat cell for an ELL edge and ``L + entry`` for an overflow edge —
+    what a caller that tombstones by position keeps per edge.
     """
     assert slice_rows >= 1 and slice_rows == next_pow2(slice_rows), slice_rows
     hub_k = next_pow2(max(hub_k, 1))
     dst = np.asarray(dst, np.int64) - row0
     assert not len(dst) or (dst.min() >= 0 and dst.max() < n), \
         f"dst outside window [row0={row0}, row0+{n})"
-    indptr, cols, ws, _ = coo_to_csr(n, np.asarray(src), dst,
-                                     np.asarray(w), by="dst")
+    indptr, cols, ws, perm = coo_to_csr(n, np.asarray(src), dst,
+                                        np.asarray(w), by="dst")
     R = -(-max(n, 1) // slice_rows) * slice_rows if n_rows is None else n_rows
     assert R >= n and R % slice_rows == 0, (R, n, slice_rows)
     n_slices = R // slice_rows
@@ -195,7 +199,13 @@ def sliced_ell_from_coo(
     ow[:n_over] = o_w
 
     fill = capped.astype(np.int32)
-    return flat_idx, flat_w, fill, widths, osrc, odst, ow, n_over
+    out = (flat_idx, flat_w, fill, widths, osrc, odst, ow, n_over)
+    if not positions:
+        return out
+    at = np.empty(len(perm), np.int64)
+    at[perm[keep]] = pos
+    at[perm[~keep]] = L + np.arange(n_over)
+    return out + (at,)
 
 
 def ell_from_coo(n: int, src: np.ndarray, dst: np.ndarray, w: np.ndarray,

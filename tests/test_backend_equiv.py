@@ -134,7 +134,7 @@ def test_frontier_modes_join_the_equivalence_contract(backend, mode,
     source = 3
     kw = dict(BACKEND_KW[backend], wave_schedule=schedule)
     dense = _run(backend, n, m, log, source, use_doubling=True,
-                 batch_deletions=False, **kw)
+                 batch_deletions=False, frontier_mode="dense", **kw)
     sparse = _run(backend, n, m, log, source, use_doubling=True,
                   batch_deletions=False, frontier_mode=mode,
                   frontier_cap=16, **kw)

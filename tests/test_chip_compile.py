@@ -23,7 +23,7 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
-from repro.core import relax
+from repro.core import frontier, relax
 from repro.core.backends.sliced import (SlicedEllPlanner, SlicedEllState,
                                         sliced_relax_wave)
 from repro.core.dist_engine import _build_epochs
@@ -86,6 +86,34 @@ def test_relax_until_converged_compiles_at_deployment_size(one_chip):
         sssp, pool, _sds((N,), jnp.bool_, one_chip),
         num_vertices=N).compile()
     assert _device_bytes(compiled) < HBM
+
+
+@pytest.mark.parametrize("epoch", ["add", "del"])
+def test_ladder_epochs_compile_at_deployment_size(one_chip, epoch):
+    """The default route's epochs (core/frontier.py): the ADD epoch's seed
+    wave over a 2^13-edge batch and the DEL epoch's recompute, each through
+    the capacity ladder, with a sidecar of 2^25 cells and a 2^23-entry
+    overflow lane."""
+    f32, i32, b = jnp.float32, jnp.int32, jnp.bool_
+    L, C, B = 1 << 25, 1 << 23, 1 << 13
+    sssp = SSSPState(_sds((N,), f32, one_chip), _sds((N,), i32, one_chip),
+                     _sds((), i32, one_chip))
+    pool = EdgePool(_sds((POOL,), i32, one_chip), _sds((POOL,), i32, one_chip),
+                    _sds((POOL,), f32, one_chip), _sds((POOL,), b, one_chip))
+    st = frontier.OutState(*(_sds(s, t, one_chip) for s, t in (
+        ((L,), i32), ((L,), f32), ((N,), i32), ((N,), i32), ((C,), i32),
+        ((C,), i32), ((C,), f32), ((N,), i32))))
+    caps = frontier.capacity_ladder(N)
+    if epoch == "add":
+        lowered = frontier.seeded_relax.lower(
+            sssp, pool, st, _sds((B,), i32, one_chip),
+            _sds((B,), i32, one_chip), _sds((B,), f32, one_chip),
+            num_vertices=N, caps=caps)
+    else:
+        lowered = frontier.sparse_invalidate_and_recompute.lower(
+            sssp, pool, st, _sds((N,), b, one_chip), num_vertices=N,
+            caps=caps)
+    assert _device_bytes(lowered.compile()) < HBM
 
 
 def _rmat_in_degrees(scale: int, edgefactor: int) -> np.ndarray:
